@@ -94,6 +94,9 @@ def main(argv: list[str] | None = None) -> None:
                         "(plus PATH.metrics.json)")
     args = parser.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.trace:
         from pathlib import Path
 

@@ -343,6 +343,9 @@ def main(argv: list[str] | None = None) -> int:
     args = parser.parse_args(argv)
 
     from repro import obs
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.verbose:
         obs.setup_logging()

@@ -49,7 +49,7 @@ from repro.engine.packed import FITNESS_ARRAY_KEYS  # noqa: F401  (re-export)
 from repro.engine.sim import commit_sorted, run_schedule  # noqa: F401  (re-export)
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(frozen=True)
 class ObjectiveWeights:
     """Weights of the multi-objective function (Eq. 8):
     ``min α · Σ U_ij x_ij + β · C_max``."""

@@ -229,13 +229,12 @@ def _ga_sweep_core(
     vmapped = jax.vmap(one, in_axes=(0, 0, 0, None, None, None))
     if shards <= 1:
         return jax.jit(vmapped)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.engine.shard import AXIS, instance_mesh
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             vmapped,
             mesh=instance_mesh(shards),
             in_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P(), P()),

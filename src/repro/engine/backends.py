@@ -9,8 +9,8 @@ is a registered :class:`ScheduleEngine` carrying capability metadata —
 * ``jax`` — the jitted rank-select population evaluator (XLA caches by
   shape, so every technique / sweep point in the same bucket shares one
   compiled program); also the vmapped multi-instance batch path;
-* ``pallas`` — the TPU Pallas kernel (interpret mode on CPU), forced
-  through the kernel inside its VMEM envelope.
+* ``pallas`` — the TPU Pallas kernel (Mosaic on a TPU, the Pallas
+  interpreter elsewhere), forced through the kernel inside its VMEM envelope.
 
 All three are **bit-for-bit equivalent in f32** (``exact_f32``) — the
 cross-backend sweep test asserts identical makespans and violation counts
@@ -158,7 +158,6 @@ def _sharded_batched_population_core(
     if shards <= 1:
         return _batched_population_core(usage_mode, constrained)
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.engine.shard import AXIS, instance_mesh
@@ -170,7 +169,7 @@ def _sharded_batched_population_core(
         in_axes=(0, 0, None, None),
     )
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             vmapped,
             mesh=instance_mesh(shards),
             in_specs=(P(AXIS), P(AXIS), P(), P()),
@@ -469,8 +468,8 @@ class JaxEngine(ScheduleEngine):
 
 @register_engine("pallas")
 class PallasEngine(ScheduleEngine):
-    """The Pallas TPU makespan kernel (interpret mode on CPU), forced
-    through the kernel inside its VMEM envelope; instances beyond the
+    """The Pallas TPU makespan kernel (the Pallas interpreter off-TPU),
+    forced through the kernel inside its VMEM envelope; instances beyond the
     envelope fall back to the jnp oracle with identical f32 semantics."""
 
     capabilities = EngineCapabilities(

@@ -1,37 +1,39 @@
 """Pallas TPU kernel: population schedule evaluation (metaheuristic fitness).
 
 This is the paper's scale bottleneck (Table IX: serial GA fitness at 500×500
-took 6513 s) re-thought for the TPU execution model rather than ported:
+took 6513 s) laid out for the TPU rather than ported:
 
-* the *population* dimension is the parallel axis — each grid step evaluates
-  a ``TILE``-wide slab of candidate assignments with all vector ops batched
-  over the tile (VPU lanes), and node-row gathers expressed as one-hot
-  contractions (MXU-friendly matmuls instead of scatter/gather, which the
-  TPU vector unit has no analogue for);
-* the sequential task loop (a true dependency chain — list scheduling) runs
-  in-kernel over VMEM-resident state: ``core_free [TILE, N, CMAX]`` and
-  ``finish [TILE, T]`` never leave VMEM;
-* the k-th-smallest-core selection uses the O(CMAX²) comparison-rank trick
-  from :mod:`repro.kernels.select` — the same primitive as the jnp oracle,
-  so the two agree bit-for-bit (no sort primitive needed on the VPU).
+* the *population* is the lane axis — each grid step evaluates a tile of up
+  to 128 candidate assignments, one candidate per vector lane, so every
+  vector op of the task loop is batched over the tile;
+* everything that depends only on the assignment (the per-candidate task
+  duration, core count and the predecessor-transfer times of Eq. 5) is a
+  gather, done by XLA before the kernel; the kernel runs only the true
+  dependency chain — list scheduling over the tasks in topological order;
+* that chain keeps its state in VMEM: the core-free times ``[N, CMAX, TILE]``
+  and the finish times ``[T, TILE]`` never leave the chip during a tile;
+* the node a candidate picked is selected by a masked pass over the node
+  axis (a per-lane gather has no vector form), and the k-th-smallest core
+  uses the comparison-rank rule of :mod:`repro.kernels.select` — the same
+  values in the same order as the jnp reference and the numpy oracle, so all
+  three agree bit for bit.
 
-Two placement modes for the task-static arrays:
+Two placement modes for the transfer times ``[T, MAXP, TILE]``:
 
-* **resident** — durations ``[T, N]`` / feasibility ``[T, N]`` live wholly in
-  VMEM (fastest; bounded by the VMEM budget),
-* **streamed** — the two big ``[T, N]`` arrays stay in HBM (``ANY`` memory
-  space) and each task step double-buffers its ``[1, N]`` row into VMEM via
-  async DMA, prefetching row ``j+1`` while computing row ``j``.  This drops
-  the VMEM footprint from O(T·N) to O(N), widening the kernel's envelope to
-  instances whose VMEM-resident placement would bust the budget.
+* **resident** — the tile's block lives wholly in VMEM;
+* **streamed** — the array stays in HBM and each task step double-buffers its
+  ``[MAXP, TILE]`` slab into VMEM by async DMA, prefetching task ``j+1``
+  while computing task ``j``.  VMEM then holds O(MAXP) instead of O(T·MAXP)
+  transfer rows per tile.
 
-``TILE`` is autotuned by ``ops.population_makespan`` (largest tile whose
-state fits the budget) rather than fixed.  Instances beyond even the
-streamed envelope fall back to the jnp oracle
-(``ref.population_makespan_ref``), which XLA streams from HBM.
+``vmem_bytes`` gives one grid step's VMEM footprint; the dispatcher in
+:mod:`repro.kernels.ops` picks the mode from it and falls back to the jnp
+reference (``ref.population_makespan_ref``) outside the envelope.
 
-Validated in interpret mode on CPU against the oracle over shape/dtype
-sweeps (tests/test_kernels_makespan.py, tests/test_fastpath_equivalence.py).
+On a TPU the kernel compiles with Mosaic; anywhere else it runs in the
+Pallas interpreter (tests/test_kernels_makespan.py,
+tests/test_fastpath_equivalence.py check it against both references, and
+tests/test_tpu_compile.py compiles it for a v5e).
 """
 
 from __future__ import annotations
@@ -43,128 +45,220 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.select import kth_from_ranks, stable_ranks, update_from_ranks
+#: TPU vector lane width — the widest population tile of one grid step
+LANES = 128
+#: VMEM one grid step may use; v5e has 128 MiB per core, and the rest is
+#: left to Mosaic's own scratch
+VMEM_BUDGET = 96 << 20
+#: SMEM for the predecessor ids and release times (1 MiB per core on v5e)
+SMEM_BUDGET = 512 << 10
 
 _NEG = -1e30
-DEFAULT_TILE = 8
+
+
+def interpret_mode() -> bool:
+    """Pallas kernels compile natively on a TPU and run in the Pallas
+    interpreter on every other platform."""
+    return jax.default_backend() != "tpu"
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def vmem_bytes(T: int, N: int, cmax: int, maxp: int, tile: int, stream: bool) -> int:
+    """VMEM bytes one grid step allocates.  Mosaic pads the last two dims of
+    every buffer to (8, 128) f32 tiles, and pipelined blocks are
+    double-buffered."""
+    lanes = _round_up(tile, LANES)
+    c8, p8, t8 = _round_up(cmax, 8), _round_up(maxp, 8), _round_up(T, 8)
+    words = N * c8 * lanes + c8 * lanes  # core-free state + gathered row
+    words += 2 * 4 * t8 * lanes  # assign, duration, cores in; finish out
+    words += 2 * (1 if stream else T) * p8 * lanes  # transfer times
+    return 4 * words
+
+
+def smem_bytes(T: int, maxp: int) -> int:
+    """SMEM bytes for the flattened predecessor ids and the release times."""
+    return 4 * T * (maxp + 1)
 
 
 def _kernel(
-    assign_ref,  # [TILE, T] int32
-    durations_ref,  # [T, N] f32 (VMEM block, or ANY/HBM when streaming)
-    cores_ref,  # [T, 1] f32
-    data_ref,  # [T, 1] f32
-    feasible_ref,  # [T, N] f32 (1.0 = feasible; ANY/HBM when streaming)
-    release_ref,  # [T, 1] f32
-    deadline_ref,  # [T, 1] f32 latest allowed finish (1e30 = unconstrained)
-    preds_ref,  # [T, MAXP] int32
-    dtr_ref,  # [N, N] f32
-    init_free_ref,  # [N, CMAX] f32
-    node_cores_ref,  # [1, N] f32
-    makespan_ref,  # [TILE, 1] f32 out
-    viol_ref,  # [TILE, 1] f32 out
-    core_free,  # scratch [TILE, N, CMAX] f32
-    finish,  # scratch [TILE, T] f32
-    *stream_scratch,  # streamed mode: row bufs [2, N] ×2 + DMA sems (2,) ×2
-    tasks: int,
+    preds_ref,  # SMEM [T * MAXP] int32, -1 = no predecessor
+    release_ref,  # SMEM [T] f32
+    assign_ref,  # [T, TILE] int32 node per task and candidate
+    dur_ref,  # [T, TILE] f32 duration of each task on its node
+    take_ref,  # [T, TILE] f32 cores each task occupies on its node
+    tt_ref,  # [T, MAXP, TILE] f32 block, or [G, T, MAXP, TILE] in HBM (streamed)
+    init_ref,  # HBM [N, CMAX, TILE] f32 initial core-free times
+    finish_ref,  # out [T, TILE] f32
+    core_free,  # scratch [N, CMAX, TILE] f32
+    row_buf,  # scratch [CMAX, TILE] f32
+    init_sem,  # DMA semaphore
+    *stream_scratch,  # streamed: tt slab double buffer [2, MAXP, TILE] + DMA sems (2,)
     maxp: int,
     stream: bool,
 ):
-    tile, n, cmax = core_free.shape
-    core_free[...] = jnp.broadcast_to(init_free_ref[...][None], (tile, n, cmax))
-    finish[...] = jnp.zeros((tile, tasks), jnp.float32)
-    viol_ref[...] = jnp.zeros((tile, 1), jnp.float32)
-
-    assign = assign_ref[...]  # [TILE, T]
-    iota_n = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)  # [1, N]
-    node_cores = node_cores_ref[...]  # [1, N]
-    dtr = dtr_ref[...]
+    tasks, tile = finish_ref.shape
+    n_nodes, cmax, _ = core_free.shape
+    init = pltpu.make_async_copy(init_ref, core_free, init_sem)
+    init.start()
+    finish_ref[...] = jnp.zeros((tasks, tile), jnp.float32)
 
     if stream:
-        dur_buf, feas_buf, dur_sem, feas_sem = stream_scratch
+        tt_buf, tt_sem = stream_scratch
+        g = pl.program_id(0)
 
-        def row_dma(slot, j):
-            return (
-                pltpu.make_async_copy(
-                    durations_ref.at[pl.ds(j, 1)], dur_buf.at[pl.ds(slot, 1)], dur_sem.at[slot]
-                ),
-                pltpu.make_async_copy(
-                    feasible_ref.at[pl.ds(j, 1)], feas_buf.at[pl.ds(slot, 1)], feas_sem.at[slot]
-                ),
-            )
+        def tt_dma(slot, j):
+            return pltpu.make_async_copy(tt_ref.at[g, j], tt_buf.at[slot], tt_sem.at[slot])
 
-        for dma in row_dma(0, 0):  # warm-up: task 0's rows
-            dma.start()
+        tt_dma(0, 0).start()
+    init.wait()
+    m_iota = jax.lax.broadcasted_iota(jnp.int32, (cmax, tile), 0)
 
-    def body(j, _):
+    def step(j, carry):
         if stream:
             slot = jax.lax.rem(j, 2)
-            nxt = jax.lax.rem(j + 1, 2)
 
             @pl.when(j + 1 < tasks)
             def _prefetch():
-                for dma in row_dma(nxt, j + 1):
-                    dma.start()
+                tt_dma(1 - slot, j + 1).start()
 
-            for dma in row_dma(slot, j):
-                dma.wait()
-            dur_row = pl.load(dur_buf, (pl.dslice(slot, 1), slice(None)))[0]  # [N]
-            feas_row = pl.load(feas_buf, (pl.dslice(slot, 1), slice(None)))[0]
+            tt_dma(slot, j).wait()
+
+            def transfer(s):
+                return tt_buf[slot, pl.ds(s, 1), :]
         else:
-            dur_row = pl.load(durations_ref, (pl.dslice(j, 1), slice(None)))[0]
-            feas_row = pl.load(feasible_ref, (pl.dslice(j, 1), slice(None)))[0]
 
-        i = jax.lax.dynamic_index_in_dim(assign, j, axis=1, keepdims=False)  # [TILE]
-        onehot_i = (iota_n == i[:, None]).astype(jnp.float32)  # [TILE, N]
+            def transfer(s):
+                return tt_ref[j, pl.ds(s, 1), :]
 
-        # --- ready time (Eq. 12 with Eq. 5 data migration) --------------------
-        rel = pl.load(release_ref, (pl.dslice(j, 1), slice(None)))[0, 0]
-        ready = jnp.full((tile,), rel, jnp.float32)
-        fin_all = finish[...]
-        preds_j = pl.load(preds_ref, (pl.dslice(j, 1), slice(None)))[0]  # [MAXP]
-        for slot_p in range(maxp):  # static unroll over max in-degree
-            p = preds_j[slot_p]
-            valid = p >= 0
-            psafe = jnp.maximum(p, 0)
-            fp = jax.lax.dynamic_index_in_dim(fin_all, psafe, axis=1, keepdims=False)
-            pn = jax.lax.dynamic_index_in_dim(assign, psafe, axis=1, keepdims=False)
-            onehot_pn = (iota_n == pn[:, None]).astype(jnp.float32)  # [TILE, N]
-            # rate = dtr[pn, i]  via one-hot row select (MXU) + masked reduce
-            rate_rows = jnp.dot(onehot_pn, dtr, preferred_element_type=jnp.float32)
-            rate = jnp.sum(rate_rows * onehot_i, axis=1)
-            d_p = pl.load(data_ref, (pl.dslice(psafe, 1), slice(None)))[0, 0]
-            tt = jnp.where(pn == i, 0.0, d_p / rate)
-            term = jnp.where(valid, fp + tt, _NEG)
-            ready = jnp.maximum(ready, term)
+        node = assign_ref[pl.ds(j, 1), :]  # [1, TILE]
 
-        # --- core selection: start at kth-smallest free time ------------------
-        cf = core_free[...]
-        row = jnp.sum(onehot_i[:, :, None] * cf, axis=1)  # [TILE, CMAX]
-        cap = jnp.sum(onehot_i * node_cores, axis=1)  # [TILE]
-        c_j = pl.load(cores_ref, (pl.dslice(j, 1), slice(None)))[0, 0]
-        c = jnp.maximum(jnp.minimum(c_j, cap), 1.0)  # [TILE] f32 core counts
-        ranks = stable_ranks(row)  # [TILE, CMAX] — shared rank-select primitive
-        kth = kth_from_ranks(row, ranks, c)
-        dur = jnp.sum(onehot_i * dur_row[None, :], axis=1)
-        start = jnp.maximum(ready, kth)
-        fin_j = start + dur
+        # --- ready time (Eq. 12 with Eq. 5 data migration) ------------------
+        def pred(s, ready):
+            q = preds_ref[j * maxp + s]
+            arrive = finish_ref[pl.ds(jnp.maximum(q, 0), 1), :] + transfer(s)
+            return jnp.where(q >= 0, jnp.maximum(ready, arrive), ready)
 
-        # --- state updates -----------------------------------------------------
-        new_row = update_from_ranks(row, ranks, c, fin_j)
-        core_free[...] = jnp.where(onehot_i[:, :, None] > 0, new_row[:, None, :], cf)
-        finish[...] = jax.lax.dynamic_update_index_in_dim(fin_all, fin_j, j, axis=1)
+        ready = jnp.full((1, tile), release_ref[j], jnp.float32)
+        ready = jax.lax.fori_loop(0, maxp, pred, ready)
 
-        feas = jnp.sum(onehot_i * feas_row[None, :], axis=1)
-        dl_j = pl.load(deadline_ref, (pl.dslice(j, 1), slice(None)))[0, 0]
-        late = (fin_j > dl_j).astype(jnp.float32)
-        viol_ref[...] += ((1.0 - feas) + late)[:, None]
-        return 0
+        # --- core selection: start at the stable k-th smallest free time ----
+        def gather(n, row):
+            return jnp.where(node == n, core_free[n], row)
 
-    jax.lax.fori_loop(0, tasks, body, 0)
-    makespan_ref[...] = jnp.max(finish[...], axis=1, keepdims=True)
+        row = jax.lax.fori_loop(0, n_nodes, gather, jnp.zeros((cmax, tile), jnp.float32))
+        row_buf[...] = row
+
+        def rank(m, acc):  # select.stable_ranks along the sublane axis
+            other = row_buf[pl.ds(m, 1), :]
+            before = (other < row) | ((other == row) & (m < m_iota))
+            return acc + before.astype(jnp.float32)
+
+        ranks = jax.lax.fori_loop(0, cmax, rank, jnp.zeros((cmax, tile), jnp.float32))
+        take = take_ref[pl.ds(j, 1), :]
+        kth = jnp.sum(jnp.where(ranks == take - 1.0, row, 0.0), axis=0, keepdims=True)
+        fin = jnp.maximum(ready, kth) + dur_ref[pl.ds(j, 1), :]
+
+        # --- state updates ----------------------------------------------------
+        new_row = jnp.where(ranks < take, fin, row)
+
+        def scatter(n, c):
+            core_free[n] = jnp.where(node == n, new_row, core_free[n])
+            return c
+
+        jax.lax.fori_loop(0, n_nodes, scatter, 0)
+        finish_ref[pl.ds(j, 1), :] = fin
+        return carry
+
+    jax.lax.fori_loop(0, tasks, step, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "stream", "interpret"))
+def _population_makespan(
+    assignments, durations, cores, data, feasible, release, pred_matrix, dtr,
+    init_free, deadline, *, tile: int, stream: bool, interpret: bool,
+):
+    P, T = assignments.shape
+    N = durations.shape[1]
+    maxp = pred_matrix.shape[1]
+    cmax = init_free.shape[1]
+    if P % tile:
+        raise ValueError(f"population {P} is not a multiple of tile {tile}")
+    if not interpret and tile % LANES:
+        # Mosaic tiles HBM and VMEM buffers in whole 128-lane rows
+        raise ValueError(f"a TPU tile is a multiple of {LANES} lanes, got {tile}")
+    f32 = jnp.float32
+    # assignment-only gathers, the same expressions as
+    # ref.population_makespan_ref, built directly with the population on the
+    # minor (lane) axis: XLA:TPU compiles these gathers in about a second,
+    # the same gathers population-major in tens of seconds
+    a = assignments.astype(jnp.int32).T  # [T, P]
+    dur = jnp.take_along_axis(durations.astype(f32), a, axis=1)  # [T, P]
+    # padding entries are "never free" (+1e30); real cores start ≤ horizon
+    node_cores = jnp.maximum(jnp.sum(init_free < 1e29, axis=1), 1).astype(f32)
+    take = jnp.maximum(jnp.minimum(cores.astype(f32)[:, None], node_cores[a]), 1.0)
+    psafe = jnp.maximum(pred_matrix.astype(jnp.int32), 0)  # [T, MAXP]
+    p_nodes = a[psafe]  # [T, MAXP, P]
+    node = a[:, None, :]
+    rate = dtr.astype(f32)[p_nodes, node]
+    tt = jnp.where(p_nodes == node, 0.0, data.astype(f32)[psafe][:, :, None] / rate)
+    if stream:  # one contiguous [MAXP, TILE] slab per (tile, task) DMA
+        tt = jnp.transpose(tt.reshape(T, maxp, P // tile, tile), (2, 0, 1, 3))
+    init = jnp.broadcast_to(init_free.astype(f32)[:, :, None], (N, cmax, tile))
+
+    def lane_block():
+        return pl.BlockSpec((T, tile), lambda g: (0, g))
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    scratch = [
+        pltpu.VMEM((N, cmax, tile), f32),
+        pltpu.VMEM((cmax, tile), f32),
+        pltpu.SemaphoreType.DMA(()),
+    ]
+    if stream:
+        scratch += [pltpu.VMEM((2, maxp, tile), f32), pltpu.SemaphoreType.DMA((2,))]
+    limit = min(vmem_bytes(T, N, cmax, maxp, tile, stream) + (16 << 20), 120 << 20)
+    finish = pl.pallas_call(
+        functools.partial(_kernel, maxp=maxp, stream=stream),
+        grid=(P // tile,),
+        in_specs=[
+            smem,
+            smem,
+            lane_block(),
+            lane_block(),
+            lane_block(),
+            hbm if stream else pl.BlockSpec((T, maxp, tile), lambda g: (0, 0, g)),
+            hbm,
+        ],
+        out_specs=lane_block(),
+        out_shape=jax.ShapeDtypeStruct((T, P), f32),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=limit
+        ),
+        interpret=interpret,
+        name="population_makespan",
+    )(
+        pred_matrix.astype(jnp.int32).reshape(-1),
+        release.astype(f32),
+        a,
+        dur,
+        take,
+        tt,
+        init,
+    )  # [T, P] finish times
+    makespan = jnp.max(finish, axis=0, initial=0.0)
+    feas = jnp.take_along_axis(feasible.astype(bool), a, axis=1)
+    violations = jnp.sum(~feas, axis=0).astype(f32)
+    if deadline is not None:
+        late = finish > deadline.astype(f32)[:, None]
+        violations = violations + jnp.sum(late, axis=0).astype(f32)
+    return makespan, violations
+
+
 def population_makespan_pallas(
     assignments: jax.Array,  # [P, T] int32
     durations: jax.Array,  # [T, N] f32
@@ -177,81 +271,21 @@ def population_makespan_pallas(
     init_free: jax.Array,  # [N, CMAX] f32
     deadline: jax.Array | None = None,  # [T] f32 (1e30 = unconstrained)
     *,
-    tile: int = DEFAULT_TILE,
+    tile: int = LANES,
     stream: bool = False,
-    interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
-    """Returns ``(makespan[P], violations[P])``.  ``P % tile == 0`` (the ops
-    wrapper pads the population).  ``stream=True`` keeps the two [T, N]
-    task-static arrays in HBM and DMA-streams rows per task step."""
+    """Returns ``(makespan[P], violations[P])``.  ``tile`` candidates share
+    one grid step; the population is padded to a multiple of it with
+    all-zero assignments, whose results are dropped.  ``stream=True`` keeps
+    the transfer times in HBM and DMA-streams one task's slab per step."""
     P, T = assignments.shape
-    N = durations.shape[1]
-    maxp = pred_matrix.shape[1]
-    cmax = init_free.shape[1]
-    assert P % tile == 0, (P, tile)
-    if deadline is None:
-        deadline = jnp.full((T,), 1e30, dtype=jnp.float32)
-    # padding entries are "never free" (+1e30); real cores start ≤ horizon
-    node_cores = jnp.sum(init_free < 1e29, axis=1).astype(jnp.float32)
-    node_cores = jnp.maximum(node_cores, 1.0).reshape(1, N)
-
-    kernel = functools.partial(_kernel, tasks=T, maxp=maxp, stream=stream)
-
-    def static(*block):
-        return pl.BlockSpec(block, lambda g: tuple(0 for _ in block))
-
-    big = (
-        pl.BlockSpec(memory_space=pltpu.ANY) if stream else None
-    )  # [T, N] arrays stay in HBM when streaming
-    scratch = [
-        pltpu.VMEM((tile, N, cmax), jnp.float32),
-        pltpu.VMEM((tile, T), jnp.float32),
-    ]
-    if stream:
-        scratch += [
-            pltpu.VMEM((2, N), jnp.float32),  # durations row double-buffer
-            pltpu.VMEM((2, N), jnp.float32),  # feasibility row double-buffer
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ]
-
-    mk, viol = pl.pallas_call(
-        kernel,
-        grid=(P // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, T), lambda g: (g, 0)),
-            big or static(T, N),
-            static(T, 1),
-            static(T, 1),
-            big or static(T, N),
-            static(T, 1),
-            static(T, 1),
-            static(T, maxp),
-            static(N, N),
-            static(N, cmax),
-            static(1, N),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile, 1), lambda g: (g, 0)),
-            pl.BlockSpec((tile, 1), lambda g: (g, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((P, 1), jnp.float32),
-            jax.ShapeDtypeStruct((P, 1), jnp.float32),
-        ],
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(
-        assignments.astype(jnp.int32),
-        durations.astype(jnp.float32),
-        cores.astype(jnp.float32).reshape(T, 1),
-        data.astype(jnp.float32).reshape(T, 1),
-        feasible.astype(jnp.float32),
-        release.astype(jnp.float32).reshape(T, 1),
-        deadline.astype(jnp.float32).reshape(T, 1),
-        pred_matrix.astype(jnp.int32),
-        dtr.astype(jnp.float32),
-        init_free.astype(jnp.float32),
-        node_cores,
+    pad = (-P) % tile
+    if pad:
+        assignments = jnp.concatenate(
+            [assignments, jnp.zeros((pad, T), assignments.dtype)], axis=0
+        )
+    mk, viol = _population_makespan(
+        assignments, durations, cores, data, feasible, release, pred_matrix,
+        dtr, init_free, deadline, tile=tile, stream=stream, interpret=interpret_mode(),
     )
-    return mk[:, 0], viol[:, 0]
+    return mk[:P], viol[:P]

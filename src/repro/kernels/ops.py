@@ -1,16 +1,17 @@
-"""Jit'd dispatch wrappers over the Pallas kernels and their jnp oracles.
+"""Dispatch wrappers over the Pallas kernels and their jnp oracles.
 
 Selection policy:
 
 * ``configure(use_pallas=...)`` or env ``REPRO_USE_PALLAS=1`` turns the
-  Pallas path on.  On CPU backends the kernels run in interpret mode
-  (functional validation); on TPU they compile natively.
-* The default on this container is the jnp oracle path — it is what the
-  512-device dry-run lowers (Pallas does not lower to the XLA:CPU backend),
-  and its FLOPs match the kernel contract, so the roofline terms are
-  representative (DESIGN.md §6).
-* ``population_makespan`` additionally falls back to the oracle whenever the
-  instance exceeds the kernel's VMEM sizing envelope.
+  Pallas path on.  The platform alone decides how a kernel runs: natively on
+  a TPU, in the Pallas interpreter anywhere else
+  (:func:`repro.kernels.makespan.interpret_mode`).
+* The default is the jnp oracle path — it is what the 512-device dry-run
+  lowers (Pallas does not lower to the XLA:CPU backend), and its FLOPs match
+  the kernel contract, so the roofline terms are representative.
+* ``population_makespan`` falls back to the oracle whenever the instance
+  exceeds the kernel's VMEM/SMEM envelope; the ``engine.dispatch.pallas`` /
+  ``engine.dispatch.ref`` counters record which path ran.
 """
 
 from __future__ import annotations
@@ -22,33 +23,24 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
-from repro.kernels import ref
+from repro.kernels import makespan, ref
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.makespan import population_makespan_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
 @dataclasses.dataclass
 class KernelConfig:
     use_pallas: bool = bool(int(os.environ.get("REPRO_USE_PALLAS", "0")))
-    interpret: bool | None = None  # None → interpret iff backend is CPU
-
-    def resolve_interpret(self) -> bool:
-        if self.interpret is not None:
-            return self.interpret
-        return jax.default_backend() != "tpu"
 
 
 _CONFIG = KernelConfig()
 
 
-def configure(use_pallas: bool | None = None, interpret: bool | None = None) -> KernelConfig:
+def configure(use_pallas: bool | None = None) -> KernelConfig:
     global _CONFIG
     if use_pallas is not None:
         _CONFIG = dataclasses.replace(_CONFIG, use_pallas=use_pallas)
-    if interpret is not None:
-        _CONFIG = dataclasses.replace(_CONFIG, interpret=interpret)
     return _CONFIG
 
 
@@ -56,44 +48,16 @@ def kernel_config() -> KernelConfig:
     return _CONFIG
 
 
-# VMEM sizing envelope for the makespan kernel (see kernels/makespan.py)
-_MAKESPAN_VMEM_WORDS = 3_000_000
-
-
-def _makespan_words(T: int, N: int, cmax: int, maxp: int, tile: int, stream: bool) -> int:
-    """f32-word VMEM footprint of one grid step of the makespan kernel."""
-    # per-task columns: cores, data, release, deadline + maxp predecessor ids
-    words = N * N + N * cmax + tile * (N * cmax + 2 * T) + T * (4 + maxp)
-    # the two big [T, N] task-static arrays: VMEM-resident, or 2×[2, N]
-    # double-buffered rows when DMA-streamed from HBM
-    words += 4 * N if stream else 2 * T * N
-    return words
-
-
-def _makespan_fits(T: int, N: int, cmax: int, maxp: int, tile: int, stream: bool) -> bool:
-    return _makespan_words(T, N, cmax, maxp, tile, stream) <= _MAKESPAN_VMEM_WORDS
-
-
-def _autotune_makespan(
-    P: int, T: int, N: int, cmax: int, maxp: int, tile: int | None
-) -> tuple[int, bool] | None:
-    """Pick ``(tile, stream)`` for the kernel, or None → jnp fallback.
-
-    Preference order: VMEM-resident task arrays with the widest tile, then
-    streamed with the widest tile (streaming re-reads T·N per grid step, so
-    a wide tile amortizes the HBM traffic), then narrow tiles.  Tiles wider
-    than the (pow2-rounded) population only pad wasted lanes — skipped."""
-    if tile is None:
-        pop_cap = 1
-        while pop_cap < min(P, 32):
-            pop_cap *= 2
-        tiles = tuple(t for t in (32, 16, 8, 4, 2, 1) if t <= pop_cap)
-    else:
-        tiles = (tile,)
+def _makespan_mode(T: int, N: int, cmax: int, maxp: int) -> bool | None:
+    """The kernel's placement of the transfer times — VMEM-resident
+    (``False``) when it fits, else DMA-streamed (``True``) — or None when
+    even the streamed kernel busts the VMEM or SMEM budget (→ jnp oracle).
+    Every TPU tile is one 128-lane population slab."""
+    if makespan.smem_bytes(T, maxp) > makespan.SMEM_BUDGET:
+        return None
     for stream in (False, True):
-        for t in tiles:
-            if _makespan_fits(T, N, cmax, maxp, t, stream):
-                return t, stream
+        if makespan.vmem_bytes(T, N, cmax, maxp, makespan.LANES, stream) <= makespan.VMEM_BUDGET:
+            return stream
     return None
 
 
@@ -109,49 +73,29 @@ def population_makespan(
     dtr: jax.Array,
     init_free: jax.Array,
     deadline: jax.Array | None = None,
-    tile: int | None = None,
     force: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Dispatch: autotuned Pallas kernel (resident → streamed) when enabled
-    and within the VMEM envelope, else the jnp oracle.  ``tile=None`` picks
-    the widest tile that fits.  ``force=True`` routes through the kernel
-    regardless of the global config (the ``pallas`` engine backend) — the
-    envelope fallback still applies.  ``deadline`` ([T] latest finish, 1e30 =
-    unconstrained) folds late tasks into the violation count."""
-    P, T = assignments.shape
+    """Dispatch: the Pallas kernel (resident → streamed) when enabled and
+    within its envelope, else the jnp oracle.  ``force=True`` routes through
+    the kernel regardless of the global config (the ``pallas`` engine
+    backend) — the envelope fallback still applies.  ``deadline`` ([T]
+    latest finish, 1e30 = unconstrained) folds late tasks into the
+    violation count."""
+    T = assignments.shape[1]
     N = durations.shape[1]
     cmax = init_free.shape[1]
     maxp = pred_matrix.shape[1]
     if deadline is None:
         deadline = jnp.full((T,), 1e30, dtype=jnp.float32)
-    use = force or _CONFIG.use_pallas
-    choice = _autotune_makespan(P, T, N, cmax, maxp, tile) if use else None
-    if choice is not None:
+    stream = _makespan_mode(T, N, cmax, maxp) if force or _CONFIG.use_pallas else None
+    # trace-time counts: under jit they record per compilation, not per
+    # executed call
+    if stream is not None:
         obs.METRICS.counter("engine.dispatch.pallas").inc()
-        tile, stream = choice
-        pad = (-P) % tile
-        if pad:
-            assignments = jnp.concatenate(
-                [assignments, jnp.zeros((pad, T), assignments.dtype)], axis=0
-            )
-        mk, viol = population_makespan_pallas(
-            assignments,
-            durations,
-            cores,
-            data,
-            feasible,
-            release,
-            pred_matrix,
-            dtr,
-            init_free,
-            deadline,
-            tile=tile,
-            stream=stream,
-            interpret=_CONFIG.resolve_interpret(),
+        return makespan.population_makespan_pallas(
+            assignments, durations, cores, data, feasible, release, pred_matrix,
+            dtr, init_free, deadline, stream=stream,
         )
-        return mk[:P], viol[:P]
-    # trace-time count only: under jit this records per compilation, not
-    # per executed call (the pallas engine path above is never jitted)
     obs.METRICS.counter("engine.dispatch.ref").inc()
     return ref.population_makespan_ref(
         assignments,
@@ -193,7 +137,7 @@ def flash_attention(
             scale=scale,
             block_q=block_q,
             block_k=block_k,
-            interpret=_CONFIG.resolve_interpret(),
+            interpret=makespan.interpret_mode(),
         )
     if Sq > 512 or Skv > 512:
         # blockwise jnp path (flash-equivalent memory behaviour under XLA)
@@ -254,7 +198,7 @@ def decode_attention(
             softcap=softcap,
             scale=scale,
             block_k=block_k,
-            interpret=_CONFIG.resolve_interpret(),
+            interpret=makespan.interpret_mode(),
         )
     return ref.decode_attention_ref(
         q, k_cache, v_cache, lengths, softcap=softcap, scale=scale
@@ -275,7 +219,7 @@ def ssd_scan(
     L = x.shape[1]
     if use and L % min(chunk, L) == 0:
         return ssd_scan_pallas(
-            x, dt, A, B_mat, C_mat, chunk=chunk, interpret=_CONFIG.resolve_interpret()
+            x, dt, A, B_mat, C_mat, chunk=chunk, interpret=makespan.interpret_mode()
         )
     if L % min(chunk, L) == 0:
         return ref.ssd_scan_chunked_ref(x, dt, A, B_mat, C_mat, chunk=min(chunk, L))

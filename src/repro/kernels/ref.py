@@ -75,7 +75,10 @@ def population_makespan_ref(
             fin = fin.at[j].set(f)
             return (core_free, fin), None
 
-        (_, fin), _ = jax.lax.scan(step, (init_free, jnp.zeros(T, jnp.float32)), jnp.arange(T))
+        # zeros shaped from an input, so under shard_map the carry varies
+        # over the same mesh axes as the state the scan writes into it
+        fin0 = jnp.zeros_like(durations[:, 0], dtype=jnp.float32)
+        (_, fin), _ = jax.lax.scan(step, (init_free, fin0), jnp.arange(T))
         makespan = jnp.max(fin, initial=0.0)
         feas = feasible[jnp.arange(T), assignment]
         violations = jnp.sum(~feas).astype(jnp.float32)

@@ -1,13 +1,14 @@
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+if __name__ == "__main__":  # importing this module must not resize jax's device set
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (architecture × shape × mesh)
 cell on the production mesh with ShapeDtypeStruct inputs (no allocation),
 record ``memory_analysis()`` / ``cost_analysis()`` / collective-operand
 bytes parsed from the compiled HLO — the §Dry-run and §Roofline evidence.
 
-The two lines above MUST precede any other import (jax locks the device
+When run as a script, the flag above MUST precede any other import (jax locks the device
 count at first init).  Usage:
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2.5-3b --shape train_4k

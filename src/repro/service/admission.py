@@ -68,12 +68,15 @@ class AdmissionStats:
     batched_groups: int = 0  # solve_batch invocations covering > 1 problem
     batched_submissions: int = 0  # problems covered by those invocations
     sharded_groups: int = 0  # batched groups striped across > 1 device
+    #: what each batched solve that raised (and was retried as singles) raised
+    batch_errors: list[str] = dataclasses.field(default_factory=list)
 
     def merge(self, other: "AdmissionStats") -> None:
         self.solver_calls += other.solver_calls
         self.batched_groups += other.batched_groups
         self.batched_submissions += other.batched_submissions
         self.sharded_groups += other.sharded_groups
+        self.batch_errors.extend(other.batch_errors)
 
 
 class AdmissionBatcher:
@@ -205,10 +208,14 @@ class AdmissionBatcher:
                     reports = batch_fn(
                         [m.problem for m in members], first.weights, **kw
                     )
-            except Exception:  # noqa: BLE001
+            except Exception as e:  # noqa: BLE001
                 # a bad member must not take the whole group down with it —
                 # whatever the batch backend raised, retry one by one so only
-                # the culprit is rejected (and its error recorded)
+                # the culprit is rejected (and its error recorded).  The batch
+                # error itself is recorded too: a device failure of the whole
+                # sweep must not pass for "nothing to batch"
+                stats.batch_errors.append(f"{type(e).__name__}: {e}")
+                obs.METRICS.counter("service.admission.batch_errors").inc()
                 singles.extend(members)
                 continue
             if reports is None:

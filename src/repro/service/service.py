@@ -194,6 +194,9 @@ class ServiceResult:
     nodes: list[dict[str, Any]]
     #: cycling stream accounting (zeros on traces without cycling specs)
     cycling: dict[str, Any] = dataclasses.field(default_factory=dict)
+    sharded_groups: int = 0  # batched groups striped across > 1 device
+    #: errors raised by batched solves that were retried as singles
+    batch_errors: list[str] = dataclasses.field(default_factory=list)
 
     def makespans(self) -> dict[str, float | None]:
         """id → observed makespan (None when rejected/unfinished) — the
@@ -226,6 +229,8 @@ class ServiceResult:
             "solver_calls": self.solver_calls,
             "batched_groups": self.batched_groups,
             "batched_submissions": self.batched_submissions,
+            "sharded_groups": self.sharded_groups,
+            "batch_errors": list(self.batch_errors),
             "events": len(self.event_log),
             "nodes": self.nodes,
         }
@@ -373,6 +378,8 @@ class SchedulingService:
         self.solver_calls = 0
         self.batched_groups = 0
         self.batched_submissions = 0
+        self.sharded_groups = 0
+        self.batch_errors: list[str] = []
         self._submissions: dict[str, Submission] = {}
         #: as-registered workflows — preemption retries swap a reduced
         #: remainder into ``_submissions``, but a spawned next cycle must
@@ -676,6 +683,8 @@ class SchedulingService:
         self.solver_calls += stats.solver_calls
         self.batched_groups += stats.batched_groups
         self.batched_submissions += stats.batched_submissions
+        self.sharded_groups += stats.sharded_groups
+        self.batch_errors.extend(stats.batch_errors)
         obs.METRICS.counter("service.solver_calls").inc(stats.solver_calls)
         obs.METRICS.counter("service.admission.batched_groups").inc(
             stats.batched_groups
@@ -878,6 +887,8 @@ class SchedulingService:
             solver_calls=self.solver_calls,
             batched_groups=self.batched_groups,
             batched_submissions=self.batched_submissions,
+            sharded_groups=self.sharded_groups,
+            batch_errors=list(self.batch_errors),
             clock_end=self.loop.now,
             wall_seconds=time.perf_counter() - wall0,
             nodes=[s.to_json() for s in self.state.status()],

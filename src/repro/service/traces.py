@@ -410,9 +410,11 @@ def generate_trace(
 
     ``topology`` draws the tenants' continuum from a generated tiered
     topology (:mod:`repro.topology`): a preset name, spec dict, or
-    :class:`~repro.topology.TopologySpec`.  Note the ``"tpu"`` family
-    requires F9 nodes, which tiered topologies do not provide — pick
-    ``families`` accordingly.
+    :class:`~repro.topology.TopologySpec`.  Only the ``"stgs"`` and
+    ``"random"`` families are feasible there: ``"tpu"`` requires F9 nodes,
+    which tiered topologies do not provide, and ``"mri"`` workflows carry
+    durations for the MRI system's own nodes (N1–N3) — pick ``families``
+    accordingly.
 
     ``cycling`` turns a seeded fraction of submissions into recurring /
     converging streams: ``{"fraction": 0.25, **cycle_spec_json}`` — the

@@ -240,17 +240,23 @@ def test_dead_link_blocks_even_zero_data_edges():
 def test_makespan_autotune_envelope():
     from repro.kernels import ops
 
-    # small instance: VMEM-resident with the widest tile
-    choice = ops._autotune_makespan(64, 200, 50, 64, 8, None)
-    assert choice == (32, False)
-    # [T, N] arrays alone bust the budget → DMA-streamed mode
-    choice = ops._autotune_makespan(64, 4000, 400, 64, 8, None)
-    assert choice is not None and choice[1] is True
-    # N² state alone busts the budget → jnp fallback
-    assert ops._autotune_makespan(64, 100000, 3000, 512, 8, None) is None
-    # tiles never exceed the pow2-rounded population
-    choice = ops._autotune_makespan(5, 200, 50, 64, 8, None)
-    assert choice is not None and choice[0] <= 8
+    from repro.kernels import makespan
+
+    # small instance: transfer times VMEM-resident
+    assert ops._makespan_mode(200, 50, 64, 8) is False
+    # the paper's largest MH cell (Table IX, 500x500) runs resident
+    assert ops._makespan_mode(500, 500, 64, 39) is False
+    # [T, MAXP] transfer times alone bust the budget → DMA-streamed mode
+    assert ops._makespan_mode(2000, 400, 64, 48) is True
+    # N·CMAX core state alone busts the budget → jnp fallback
+    assert ops._makespan_mode(12, 3000, 512, 4) is None
+    # predecessor ids beyond SMEM → jnp fallback
+    assert ops._makespan_mode(5000, 10, 8, 200) is None
+    # streaming never costs VMEM, and the budget leaves Mosaic headroom on v5e
+    for shape in [(200, 50, 64, 8), (500, 500, 64, 39), (2000, 400, 64, 48)]:
+        res = makespan.vmem_bytes(*shape, makespan.LANES, False)
+        assert makespan.vmem_bytes(*shape, makespan.LANES, True) <= res
+    assert makespan.VMEM_BUDGET < 128 << 20
 
 
 def test_weighted_usage_mode_batched():

@@ -232,6 +232,52 @@ def test_declined_batch_is_not_reported_as_batched():
     assert r.solver_calls == 2
 
 
+def test_raising_batch_solve_is_recorded_then_retried_as_singles():
+    """A batched solve that raises (say, a device compile failure of the
+    whole sweep) still falls back to per-member solves, but what it raised is
+    kept on the admission stats, the service result and a metrics counter —
+    it must not pass for "nothing to batch"."""
+    from repro.core.api import REGISTRY, SolverRegistry
+    from repro.obs import METRICS, MetricsRegistry
+    from repro.service.admission import AdmissionBatcher, PreparedSubmission
+    from repro.service.cache import SolveCache
+
+    def sweep_crash(problems, weights=None, **kw):
+        raise RuntimeError("synthetic sweep crash")
+
+    reg = SolverRegistry()
+    reg.register("heft", REGISTRY.get("heft").fn, batch_fn=sweep_crash)
+    subs = tuple(
+        _sub(i, _chain(f"X{i}", [1.0 + i, 2.0]), t=0.0) for i in range(2)
+    )
+    trace = Trace(name="sweepcrash", system=_two_node_system(), submissions=subs)
+    before = METRICS.snapshot()
+    r = SchedulingService(trace.system, ServiceConfig(batch_window=0.5),
+                          registry=reg).run(trace)
+    delta = MetricsRegistry.delta(before, METRICS.snapshot())["counters"]
+    assert [rec.status for rec in r.records] == ["completed", "completed"]
+    assert r.batched_groups == 0 and r.solver_calls == 2
+    assert r.batch_errors == ["RuntimeError: synthetic sweep crash"]
+    assert r.summary()["batch_errors"] == r.batch_errors
+    assert delta["service.admission.batch_errors"] == 1
+
+    # the same record straight off the batcher's stats
+    from repro.core.workload_model import Workload, build_problem
+
+    preps = [
+        PreparedSubmission(
+            submission=s,
+            problem=build_problem(trace.system, Workload((s.workflow,))),
+            key=f"k{i}",
+            baked={},
+        )
+        for i, s in enumerate(subs)
+    ]
+    stats = AdmissionBatcher(reg, SolveCache(16)).admit(preps)
+    assert stats.batch_errors == ["RuntimeError: synthetic sweep crash"]
+    assert all(p.schedule is not None for p in preps)
+
+
 def test_service_config_rejects_degenerate_knobs():
     with pytest.raises(ValueError, match="max_batch"):
         ServiceConfig(max_batch=0)  # would spin the admit loop forever
