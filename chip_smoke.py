@@ -184,9 +184,9 @@ def phase_table9(seed: int, sizes: dict, compiles: Compiles) -> None:
             check(problems == [], f"{label} schedule invalid: {problems[:3]}")
             check(rep.fallbacks == (), f"{label} fell back: {rep.fallbacks}")
             if engine == "pallas":  # each solve traces, so counts, its dispatch
-                check(counter_delta(before, "engine.dispatch.ref") == 0,
+                check(counter_delta(before, "engine.traced.ref") == 0,
                       f"{label} evaluated through the jnp reference, not the kernel")
-                check(counter_delta(before, "engine.dispatch.pallas") >= 1,
+                check(counter_delta(before, "engine.traced.pallas") >= 1,
                       f"{label} never dispatched the Pallas kernel")
             makespans[label] = float(rep.schedule.makespan)
         # the kernel and the jnp core trace the same GA, so the same schedule

@@ -23,6 +23,7 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.evaluator import (
     ObjectiveWeights,
     Schedule,
@@ -73,7 +74,9 @@ def _finish(
     t0: float,
     history: np.ndarray,
 ) -> MHResult:
-    sched = evaluate_assignment(problem, best_assignment, weights, technique=technique)
+    """Rescore the best assignment with the host oracle (``mh.finish``)."""
+    with obs.TRACER.span("mh.finish", cat="engine"):
+        sched = evaluate_assignment(problem, best_assignment, weights, technique=technique)
     sched.solve_time = time.perf_counter() - t0
     return MHResult(schedule=sched, history=history)
 
@@ -269,13 +272,48 @@ def ga_sweep(
     per-instance PRNG streams and row computations are unchanged, so the
     sharded sweep's schedules are bit-identical to the single-device sweep
     at the same seed."""
+    t0 = time.perf_counter()
+    B = len(problems)
+    # one span tree per call: everything before the device program is
+    # ``prepare``, the program and its blocking fetch ``device``, and each
+    # host rescoring ``mh.finish``
+    with obs.TRACER.span("mh.ga_sweep", cat="engine", args={"instances": B}) as call:
+        with obs.TRACER.span("mh.ga_sweep.prepare", cat="engine") as prepare:
+            run, inputs, shards, bucket, h2d_bytes = _ga_sweep_inputs(
+                problems, weights, pop_size, generations, tournament, elite, seed, shard
+            )
+            prepare.set(h2d_bytes=h2d_bytes)
+        call.set(shards=shards, bucket="x".join(str(x) for x in bucket))
+        with obs.TRACER.span("mh.ga_sweep.device", cat="engine"):
+            best, hist = run(*inputs, weights.alpha, weights.beta, mutation_rate)
+            best, hist = np.asarray(best)[:B], np.asarray(hist)[:B]
+        obs.METRICS.counter("mh.ga_sweep.instances").inc(B)
+        obs.METRICS.gauge("mh.ga_sweep.shards").set(shards)
+        return [
+            _finish(
+                problem,
+                weights,
+                best[b, : problem.num_tasks].astype(np.int64),
+                "ga",
+                t0,
+                hist[b],
+            )
+            for b, problem in enumerate(problems)
+        ]
+
+
+def _ga_sweep_inputs(problems, weights, pop_size, generations, tournament, elite, seed,
+                     shard):
+    """The host work of a sweep call before its device program: the shard
+    count, the stacked instances, the logits and the PRNG keys, on the
+    device.  Returns ``(program, (arrays, logits, keys), shards, bucket,
+    h2d_bytes)``; ``h2d_bytes`` counts what this call copies to the device
+    (a sharded stack stays resident in the pack LRU and is not copied)."""
     import jax
     import jax.numpy as jnp
 
-    from repro import obs
     from repro.engine import shard as shard_mod
 
-    t0 = time.perf_counter()
     B = len(problems)
     if shard == "auto":
         shards = shard_mod.choose_shards(B)
@@ -286,9 +324,11 @@ def ga_sweep(
     if shards > 1:
         stack = shard_mod.stack_packed_sharded(problems, shards=shards)
         arrays, bucket, Bp = stack.arrays, stack.bucket, stack.padded
+        h2d_bytes = 0
     else:
         arrays, bucket = stack_packed(problems)
         Bp = B
+        h2d_bytes = sum(a.nbytes for a in arrays.values())
     Tb, Nb = bucket[0], bucket[1]
     logits = np.full((Bp, Tb, Nb), _NEG, dtype=np.float32)
     for b, problem in enumerate(problems):
@@ -308,28 +348,8 @@ def ga_sweep(
         keys_dev = jax.device_put(keys, sharding)
     else:
         logits_dev, keys_dev = jnp.asarray(logits), jnp.asarray(keys)
-    with obs.TRACER.span(
-        "mh.ga_sweep", cat="engine",
-        args={"instances": B, "shards": shards,
-              "bucket": "x".join(str(x) for x in bucket)},
-    ):
-        best, hist = run(
-            arrays, logits_dev, keys_dev, weights.alpha, weights.beta, mutation_rate
-        )
-        best, hist = np.asarray(best)[:B], np.asarray(hist)[:B]
-    obs.METRICS.counter("mh.ga_sweep.instances").inc(B)
-    obs.METRICS.gauge("mh.ga_sweep.shards").set(shards)
-    return [
-        _finish(
-            problem,
-            weights,
-            best[b, : problem.num_tasks].astype(np.int64),
-            "ga",
-            t0,
-            hist[b],
-        )
-        for b, problem in enumerate(problems)
-    ]
+    h2d_bytes += logits.nbytes + keys.nbytes
+    return run, (arrays, logits_dev, keys_dev), shards, bucket, h2d_bytes
 
 
 # -----------------------------------------------------------------------------

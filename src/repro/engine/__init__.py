@@ -25,8 +25,13 @@ Solvers consume the engine through :func:`population_fitness_fn` /
 :func:`evaluate_population_batch`; out-of-tree backends register with
 ``@register_engine("name")`` and are immediately routable by
 ``Scenario(engine=...)``.
+
+
+Importing the package installs :mod:`repro.obs`'s ``jax.compile.*``
+counters, which the engine's compile-vs-execute accounting reads.
 """
 
+from repro import obs
 from repro.engine.backends import (
     ENGINES,
     EngineCapabilities,
@@ -60,6 +65,8 @@ from repro.engine.shard import (
     stack_packed_sharded,
 )
 from repro.engine.sim import CoreSim, commit_sorted, run_schedule
+
+obs.install_compile_counters()
 
 __all__ = [
     "ENGINES",

@@ -88,26 +88,35 @@ def population_fitness_from_arrays(
     problems keep today's exact XLA program) threads packed deadlines into
     the makespan scan's violation count and adds the budget-overage penalty,
     so GA/PSO candidates are penalized inside the batched device path with
-    no per-candidate host round-trip."""
+    no per-candidate host round-trip.
+
+    The body runs under ``jax.named_scope("fitness")``: every compiled
+    program that evaluates candidates carries a ``fitness`` segment in the
+    ``op_name`` of the evaluator's operations, which is how a profiler trace
+    tells them from the search around them.  Metadata only; no number
+    changes."""
+    import jax
+
     from repro.kernels import ref
 
-    makespan, violations = ref.population_makespan_ref(
-        assignments,
-        durations=arrays["durations"],
-        cores=arrays["cores"],
-        data=arrays["data"],
-        feasible=arrays["feasible"],
-        release=arrays["release"],
-        pred_matrix=arrays["pred_matrix"],
-        dtr=arrays["dtr"],
-        init_free=arrays["init_free"],
-        node_cores=arrays["node_cores"],
-        deadline=arrays["deadline"] if constrained else None,
-    )
-    if constrained:
-        violations = violations + _budget_overage(arrays, assignments)
-    usage = _usage_term(arrays, assignments, usage_mode)
-    obj = alpha * usage + beta * makespan + BIG_PENALTY * violations
+    with jax.named_scope("fitness"):
+        makespan, violations = ref.population_makespan_ref(
+            assignments,
+            durations=arrays["durations"],
+            cores=arrays["cores"],
+            data=arrays["data"],
+            feasible=arrays["feasible"],
+            release=arrays["release"],
+            pred_matrix=arrays["pred_matrix"],
+            dtr=arrays["dtr"],
+            init_free=arrays["init_free"],
+            node_cores=arrays["node_cores"],
+            deadline=arrays["deadline"] if constrained else None,
+        )
+        if constrained:
+            violations = violations + _budget_overage(arrays, assignments)
+        usage = _usage_term(arrays, assignments, usage_mode)
+        obj = alpha * usage + beta * makespan + BIG_PENALTY * violations
     return obj, makespan
 
 
@@ -413,10 +422,9 @@ class JaxEngine(ScheduleEngine):
         bucket, mode = packed.bucket, w.usage_mode
 
         def fitness(assignments):
-            # compile-vs-execute split: a call during which the jit cache
-            # grew is a compile; the rest are steady-state executes
-            with obs.FITNESS.measure("jax", bucket, mode,
-                                     cache_size=core._cache_size):
+            # compile-vs-execute split: a call during which JAX compiled
+            # anything is a compile; the rest are steady-state executes
+            with obs.FITNESS.measure("jax", bucket, mode):
                 return core(_pad_population(assignments, tb), arrays, w.alpha, w.beta)
 
         return fitness
@@ -456,8 +464,7 @@ class JaxEngine(ScheduleEngine):
         def fitness(assignments):
             import jax.numpy as jnp
 
-            with obs.FITNESS.measure("jax-batch", bucket, w.usage_mode,
-                                     cache_size=core._cache_size):
+            with obs.FITNESS.measure("jax-batch", bucket, w.usage_mode):
                 return core(jnp.asarray(assignments), arrays, w.alpha, w.beta)
 
         fitness.bucket = bucket  # type: ignore[attr-defined]
@@ -493,8 +500,6 @@ class PallasEngine(ScheduleEngine):
 
         def fitness(assignments):
             a = _pad_population(assignments, tb).astype(jnp.int32)
-            # no jit-cache probe for the kernel path: the first call per
-            # bucket (autotune + kernel build) counts as the compile
             with obs.FITNESS.measure("pallas", packed.bucket, w.usage_mode):
                 return _pallas_obj(a)
 

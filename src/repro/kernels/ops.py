@@ -10,8 +10,9 @@ Selection policy:
   lowers (Pallas does not lower to the XLA:CPU backend), and its FLOPs match
   the kernel contract, so the roofline terms are representative.
 * ``population_makespan`` falls back to the oracle whenever the instance
-  exceeds the kernel's VMEM/SMEM envelope; the ``engine.dispatch.pallas`` /
-  ``engine.dispatch.ref`` counters record which path ran.
+  exceeds the kernel's VMEM/SMEM envelope.  The ``engine.traced.pallas`` /
+  ``engine.traced.ref`` counters count how often each path was *traced*:
+  under ``jit`` that is once per compiled program, not once per call.
 """
 
 from __future__ import annotations
@@ -91,12 +92,12 @@ def population_makespan(
     # trace-time counts: under jit they record per compilation, not per
     # executed call
     if stream is not None:
-        obs.METRICS.counter("engine.dispatch.pallas").inc()
+        obs.METRICS.counter("engine.traced.pallas").inc()
         return makespan.population_makespan_pallas(
             assignments, durations, cores, data, feasible, release, pred_matrix,
             dtr, init_free, deadline, stream=stream,
         )
-    obs.METRICS.counter("engine.dispatch.ref").inc()
+    obs.METRICS.counter("engine.traced.ref").inc()
     return ref.population_makespan_ref(
         assignments,
         durations=durations,

@@ -5,12 +5,14 @@ module without cycles):
 
 * **Tracing** (:mod:`.tracer`): nested spans on dual clocks — wall
   (``time.perf_counter``) and the service's deterministic virtual event
-  clock.  Zero-cost when disabled; deterministic span ids so traces
+  clock — which also land in any ``jax.profiler`` trace, beside the device
+  operations.  Zero-cost when disabled; deterministic span ids so traces
   replay bit-identically at a fixed seed.
 * **Metrics** (:mod:`.metrics`): process-wide counters / gauges /
   fixed-bucket histograms plus collectors registered by owning modules
   (pack cache, jit caches), behind one ``snapshot()``/``delta()``
-  surface; JAX compile-vs-execute attribution via :data:`FITNESS`.
+  surface; JAX compile-vs-execute attribution via :data:`FITNESS`, and
+  compile counters from JAX's own events (:mod:`.jaxevents`).
 * **Export** (:mod:`.export`): Chrome/Perfetto ``trace_event`` JSON,
   flat metrics JSON, and the ``telemetry`` block embedded in campaign
   results and ``BENCH_*.json`` artifacts.
@@ -40,6 +42,7 @@ from .metrics import (
     nearest_rank,
 )
 from .tracer import TRACER, Span, Tracer, traced, virtual_fingerprint
+from .jaxevents import install_compile_counters
 from .export import (
     flatten,
     summarize_trace,
@@ -63,6 +66,7 @@ __all__ = [
     "nearest_rank",
     "FITNESS",
     "FitnessAccounting",
+    "install_compile_counters",
     "trace_events",
     "write_trace",
     "telemetry",

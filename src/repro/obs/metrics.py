@@ -36,6 +36,8 @@ __all__ = [
     "METRICS",
     "FitnessAccounting",
     "FITNESS",
+    "COMPILE_REQUESTS",
+    "CACHE_HITS",
 ]
 
 
@@ -222,51 +224,58 @@ def _sub(a: Any, b: Any) -> Any:
 METRICS = MetricsRegistry()
 
 
+#: counters kept by :func:`repro.obs.jaxevents.install_compile_counters`
+COMPILE_REQUESTS = "jax.compile.requests"
+CACHE_HITS = "jax.compile.cache_hits"
+
+
+def _compile_requests() -> float:
+    return METRICS.counter(COMPILE_REQUESTS).value
+
+
 class _Measure:
     """Context manager for one timed engine-fitness call (see below)."""
 
-    __slots__ = ("_acct", "_key", "_cache_size", "_t0", "_size0")
+    __slots__ = ("_acct", "_key", "_t0", "_requests0")
 
-    def __init__(self, acct: "FitnessAccounting", key: str,
-                 cache_size: Callable[[], int] | None) -> None:
+    def __init__(self, acct: "FitnessAccounting", key: str) -> None:
         self._acct = acct
         self._key = key
-        self._cache_size = cache_size
 
     def __enter__(self) -> "_Measure":
-        self._size0 = self._cache_size() if self._cache_size is not None else None
+        self._requests0 = _compile_requests()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
         dt_us = (time.perf_counter() - self._t0) * 1e6
         if et is None:
-            self._acct._record(self._key, dt_us, self._size0, self._cache_size)
+            self._acct._record(self._key, dt_us, _compile_requests() > self._requests0)
         return False
 
 
 class FitnessAccounting:
     """Per-(backend, shape-bucket, mode) compile-vs-execute attribution.
 
-    A call counts as a **compile** when the backend's jit cache grew during
-    it (``cache_size`` callable, jax backends) or — when no cache probe is
-    available (pallas: autotune + first kernel build) — when it is the
-    first call for its key.  Everything else is steady-state **execute**.
-    ``calls - compiles`` is therefore the jit-cache hit count."""
+    A call counts as a **compile** when JAX's compile-request counter
+    (``jax.compile.requests``, kept from ``jax.monitoring`` events by
+    :func:`repro.obs.jaxevents.install_compile_counters`) grew during it:
+    any program the call compiled or read from the persistent cache, the
+    fitness core's and every other.  Everything else is steady-state
+    **execute**, so ``calls - compiles`` is the count of calls that
+    compiled nothing."""
 
     __slots__ = ("_table",)
 
     def __init__(self) -> None:
         self._table: dict[str, dict[str, float]] = {}
 
-    def measure(self, backend: str, bucket: Any, mode: str = "",
-                cache_size: Callable[[], int] | None = None) -> _Measure:
+    def measure(self, backend: str, bucket: Any, mode: str = "") -> _Measure:
         key = f"{backend}|{'x'.join(str(d) for d in bucket)}" + (
             f"|{mode}" if mode else "")
-        return _Measure(self, key, cache_size)
+        return _Measure(self, key)
 
-    def _record(self, key: str, dt_us: float, size0: int | None,
-                cache_size: Callable[[], int] | None) -> None:
+    def _record(self, key: str, dt_us: float, is_compile: bool) -> None:
         rec = self._table.get(key)
         if rec is None:
             rec = self._table[key] = {
@@ -274,10 +283,6 @@ class FitnessAccounting:
                 "compile_us": 0.0, "execute_us": 0.0,
             }
         rec["calls"] += 1
-        if cache_size is not None and size0 is not None:
-            is_compile = cache_size() > size0
-        else:
-            is_compile = rec["calls"] == 1
         if is_compile:
             rec["compiles"] += 1
             rec["compile_us"] += dt_us

@@ -20,6 +20,12 @@ Design constraints, in priority order:
   depend only on the workload + seed.  Wall times are explicitly outside
   the determinism contract — :func:`virtual_fingerprint` hashes everything
   *except* wall fields so tests can assert bit-identical traces.
+* **One clock with the device.**  While tracing is enabled and ``jax`` is
+  already imported, every span also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so a ``jax.profiler``
+  trace (TensorBoard, Perfetto) shows the program's spans on its host plane
+  beside the device operations.  ``jax`` is looked up in ``sys.modules``
+  and never imported here: ``repro.obs`` stays stdlib-only.
 * **Exceptions are data.**  A span exited by an exception records
   ``args["error"] = "Type: message"`` and re-raises; the fallback chain in
   :func:`repro.core.api.solve_with_fallback` reads as a trail of attempt
@@ -31,6 +37,7 @@ from __future__ import annotations
 import functools
 import hashlib
 import json
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable, Sequence
@@ -93,7 +100,7 @@ _NOOP = _Noop()
 class _Active:
     """Context manager for one live span (tracing enabled)."""
 
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_span", "_t0")
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_span", "_t0", "_note")
 
     def __init__(self, tr: "Tracer", name: str, cat: str,
                  args: dict[str, Any] | None) -> None:
@@ -102,6 +109,7 @@ class _Active:
         self._cat = cat
         self._args = args
         self._span: Span | None = None
+        self._note = None
 
     def __enter__(self) -> "_Active":
         tr = self._tr
@@ -122,6 +130,10 @@ class _Active:
         self._span = span
         tr.spans.append(span)
         tr._stack.append(sid)
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            self._note = profiler.TraceAnnotation(self._name)
+            self._note.__enter__()
         return self
 
     def set(self, **kw: Any) -> "_Active":
@@ -138,6 +150,9 @@ class _Active:
         span = self._span
         if span is None:  # never entered
             return False
+        if self._note is not None:
+            self._note.__exit__(et, ev, tb)
+            self._note = None
         span.wall_dur = time.perf_counter() - self._t0
         if span.vt0 is not None and tr._vclock is not None:
             span.vdur = float(tr._vclock()) - span.vt0
@@ -203,6 +218,13 @@ class Tracer:
 
     def disable(self) -> None:
         self.enabled = False
+
+    def innermost(self) -> str | None:
+        """Name of the innermost open span, or ``None`` outside any span."""
+        if not self._stack:
+            return None
+        sid = self._stack[-1]
+        return self.spans[sid].name if sid < len(self.spans) else None
 
     def set_virtual_clock(
         self, clock: Callable[[], float] | None

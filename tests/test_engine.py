@@ -379,3 +379,74 @@ def test_engine_selection_never_leaks_into_exact_solvers():
     problem = build_problem(mri_system(), mri_workload())
     rep = route_problem(problem, technique="auto", engine="pallas")
     assert rep.schedule.violations == 0
+
+
+# -----------------------------------------------------------------------------
+# the ``fitness`` scope in the device programs
+# -----------------------------------------------------------------------------
+
+
+def _sweep_program_inputs():
+    import jax
+
+    from repro.engine import stack_packed
+
+    system = synthetic_system(3, seed=3)
+    problems = [
+        build_problem(system, Workload((random_layered_workflow(10, seed=100 + i, max_cores=4),)))
+        for i in range(2)
+    ]
+    arrays, bucket = stack_packed(problems)
+    logits = np.zeros((2, bucket[0], bucket[1]), np.float32)
+    keys = np.asarray(jax.random.split(jax.random.PRNGKey(0), 2))
+    return arrays, logits, keys, 1.0, 1.0, 0.08
+
+
+def _fitness_fusions(compiled_text: str) -> list[str]:
+    import re
+
+    fusions = re.findall(r'^\s*(?:ROOT )?%?(\S*fusion\S*) = .*op_name="([^"]*)"',
+                         compiled_text, re.M)
+    return [name for name, op_name in fusions if "fitness" in op_name.split("/")]
+
+
+def test_ga_sweep_program_carries_fitness_scope():
+    """The compiled GA sweep names the evaluator's fused ops: their
+    ``op_name`` has a ``fitness`` segment, which a profiler trace shows."""
+    from repro.core.metaheuristics import _ga_sweep_core
+
+    run = _ga_sweep_core("fixed", 8, 3, 4, 2)
+    text = run.lower(*_sweep_program_inputs()).compile().as_text()
+    assert _fitness_fusions(text)
+
+
+def test_fitness_scope_is_metadata_only(monkeypatch):
+    """With and without the scope, the sweep compiles to the same program
+    once metadata is stripped, and returns the same bits."""
+    import contextlib
+    import re
+
+    import jax
+
+    from repro.core.metaheuristics import _ga_sweep_one
+
+    def compiled(scoped: bool):
+        if not scoped:
+            monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        one = _ga_sweep_one("fixed", 8, 3, 4, 2)
+        program = jax.jit(jax.vmap(one, in_axes=(0, 0, 0, None, None, None)))
+        inputs = _sweep_program_inputs()
+        text = program.lower(*inputs).compile().as_text()
+        out = [np.asarray(x) for x in program(*inputs)]
+        monkeypatch.undo()
+        return text, out
+
+    (with_text, with_out), (bare_text, bare_out) = compiled(True), compiled(False)
+    assert _fitness_fusions(with_text) and not _fitness_fusions(bare_text)
+
+    def strip(text):  # the instructions alone: no metadata, no source table
+        return re.sub(r", metadata=\{[^}]*\}", "", text.split("\nFileNames", 1)[0])
+
+    assert strip(with_text) == strip(bare_text)
+    for a, b in zip(with_out, bare_out):
+        np.testing.assert_array_equal(a, b)

@@ -11,9 +11,12 @@ The conftest pins the in-process suite to ONE virtual device
   (f32 objectives + makespans) to the single-device vmapped core AND to the
   numpy oracle; the pad edge (B not divisible by the shard count); sharded
   ``ga_sweep`` returning the same schedules/histories as ``shard="off"``;
-  per-device pack-cache residency across all 8 devices.
+  per-device pack-cache residency across all 8 devices;
+* the span tree of one traced ``ga_sweep`` call, on one device in-process
+  and on four virtual devices in a subprocess.
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -174,6 +177,82 @@ def test_ga_sweep_shard_off_matches_default_on_one_device():
             ra.schedule.assignment, rb.schedule.assignment
         )
         np.testing.assert_array_equal(ra.history, rb.history)
+
+
+# -----------------------------------------------------------------------------
+# the span tree of one traced ga_sweep call
+# -----------------------------------------------------------------------------
+
+
+def _check_sweep_span_tree(spans, instances: int, shards: int) -> None:
+    """``spans``: ``(id, parent, name, wall_t0, wall_dur, args)`` of one
+    traced call.  It is one ``mh.ga_sweep`` tree: ``prepare``, ``device``
+    and one ``mh.finish`` per instance as its children, in that order, with
+    the pack (or shard stack) inside ``prepare``, covering at least 95% of
+    the call's wall time."""
+    by_id = {s[0]: s for s in spans}
+    (root,) = [s for s in spans if s[1] is None]
+    assert root[2] == "mh.ga_sweep"
+    assert root[5]["instances"] == instances and root[5]["shards"] == shards
+    assert "bucket" in root[5]
+    children = [s for s in spans if s[1] == root[0]]
+    assert [s[2] for s in children] == (
+        ["mh.ga_sweep.prepare", "mh.ga_sweep.device"] + ["mh.finish"] * instances)
+    prepare = children[0]
+    assert prepare[5]["h2d_bytes"] > 0
+    inside = {s[2] for s in spans if s[1] is not None and by_id[s[1]][2] == prepare[2]}
+    assert inside == ({"engine.shard_stack"} if shards > 1 else {"engine.pack"})
+    assert sum(s[4] for s in children) >= 0.95 * root[4]
+    for s in children:  # children nest inside the call and do not overlap
+        assert root[3] <= s[3] and s[3] + s[4] <= root[3] + root[4] + 1e-9
+    for a, b in zip(children, children[1:]):
+        assert a[3] + a[4] <= b[3] + 1e-9
+
+
+def _traced_sweep_spans(problems, **kw):
+    from repro import obs
+    from repro.core.metaheuristics import ga_sweep
+
+    ga_sweep(problems, pop_size=8, generations=3, seed=0, **kw)  # compile
+    obs.enable_tracing()
+    try:
+        ga_sweep(problems, pop_size=8, generations=3, seed=1, **kw)
+    finally:
+        obs.disable_tracing()
+    return [(s.id, s.parent, s.name, s.wall_t0, s.wall_dur, s.args)
+            for s in obs.TRACER.spans]
+
+
+def test_ga_sweep_span_tree_one_device():
+    _check_sweep_span_tree(_traced_sweep_spans(_family(3)), instances=3, shards=1)
+
+
+_SPAN_TREE_SCRIPT = textwrap.dedent(
+    """
+    import json, sys
+    sys.path.insert(0, "tests")
+    from test_engine_shard import _family, _traced_sweep_spans
+    from repro.engine import local_device_count
+
+    assert local_device_count() == 4, local_device_count()
+    print("SPANS " + json.dumps(_traced_sweep_spans(_family(8))))
+    """
+)
+
+
+def test_ga_sweep_span_tree_four_devices_subprocess():
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("REPRO_SHARD_DEVICES", None)
+    env["PYTHONPATH"] = str(REPO / "src")
+    proc = subprocess.run(
+        [sys.executable, "-c", _SPAN_TREE_SCRIPT],
+        capture_output=True, text=True, env=env, timeout=600, cwd=REPO,
+    )
+    assert proc.returncode == 0, f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
+    line = next(x for x in proc.stdout.splitlines() if x.startswith("SPANS "))
+    _check_sweep_span_tree(json.loads(line[len("SPANS "):]), instances=8, shards=4)
 
 
 # -----------------------------------------------------------------------------

@@ -8,7 +8,10 @@
   "after" level), nearest-rank percentiles, fixed-bucket histograms,
   ``PackStats.delta``;
 * JAX cost attribution — pinned compile-vs-execute split for one engine
-  bucket and the jit-cache-growth detection semantics;
+  bucket, compile detection from JAX's own ``jax.monitoring`` events, and
+  the ``jax.compile.*`` counters;
+* the profiler's clock — spans land as host events in a ``jax.profiler``
+  trace;
 * exporters — Perfetto ``trace_event`` schema validity (round-trip
   through :func:`repro.obs.summarize_trace`), malformed-file rejection,
   and the ``telemetry`` block shape;
@@ -249,23 +252,31 @@ def test_pack_stats_delta():
 # JAX cost attribution
 # ---------------------------------------------------------------------------
 
+def _compile_event(seconds: float = 0.01) -> None:
+    """What JAX records for one compile request (see ``obs.jaxevents``)."""
+    import jax
+
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", seconds)
+
+
 def test_fitness_accounting_cache_growth_detection():
+    import repro.engine  # noqa: F401 — installs the jax.compile.* counters
+
     acct = FitnessAccounting()
-    cache = {"size": 0}
 
     def call(grow: bool) -> None:
-        with acct.measure("fake", (4, 2, 8, 3), "fixed",
-                          cache_size=lambda: cache["size"]):
+        with acct.measure("fake", (4, 2, 8, 3), "fixed"):
             if grow:
-                cache["size"] += 1
+                _compile_event()
 
-    call(grow=True)   # compile: cache grew during the call
-    call(grow=False)  # execute (jit-cache hit)
+    call(grow=True)   # compile: JAX compiled during the call
+    call(grow=False)  # execute (nothing compiled)
     call(grow=False)
     table = acct.to_json()
     rec = table["fake|4x2x8x3|fixed"]
     assert rec["calls"] == 3 and rec["compiles"] == 1
-    assert rec["execute_calls"] == 2  # calls - compiles == jit-cache hits
+    assert rec["execute_calls"] == 2  # calls - compiles: calls that compiled nothing
     assert rec["compile_us"] > 0.0 and rec["execute_us"] >= 0.0
     assert rec["execute_us_mean"] == pytest.approx(rec["execute_us"] / 2)
     acct.reset()
@@ -313,8 +324,85 @@ def test_engine_dispatch_counters_tick():
         pack(problem), ObjectiveWeights())
     fitness(np.zeros((2, problem.num_tasks), dtype=np.int32))
     d = MetricsRegistry.delta(before, METRICS.snapshot())["counters"]
-    # the pallas engine routed through exactly one makespan dispatch path
-    assert d.get("engine.dispatch.pallas", 0) + d.get("engine.dispatch.ref", 0) >= 1
+    # the pallas engine traced through the kernel path, not the jnp one
+    assert d.get("engine.traced.pallas", 0) >= 1
+    assert d.get("engine.traced.ref", 0) == 0
+    assert not any(k.startswith("engine.dispatch.") for k in d)
+
+
+def test_compile_counters_from_jax_events():
+    """``jax.compile.requests`` counts JAX's compile requests (a fresh jit
+    compile does), ``jax.compile.cache_hits`` the persistent cache's hits,
+    and ``jax.compile.in.<span>`` the innermost open span's, while tracing
+    is on; installing twice registers the listeners once."""
+    import jax
+    import jax.numpy as jnp
+
+    obs.install_compile_counters()
+    obs.install_compile_counters()
+    x = jnp.zeros((7, 3, 5)).block_until_ready()  # a shape no other test compiles
+    before = METRICS.snapshot()
+    jax.jit(lambda x: x * 3 + 1)(x).block_until_ready()
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    d = MetricsRegistry.delta(before, METRICS.snapshot())["counters"]
+    assert d["jax.compile.requests"] == 1
+    assert d["jax.compile.cache_hits"] >= 1  # the event above, and a real hit if any
+    assert not any(k.startswith("jax.compile.in.") for k in d)  # tracing off
+
+    TRACER.enable()
+    before = METRICS.snapshot()
+    with TRACER.span("outer"):
+        with TRACER.span("step.that.compiles"):
+            _compile_event()
+        _compile_event()
+    _compile_event()  # outside any span: counted in requests only
+    d = MetricsRegistry.delta(before, METRICS.snapshot())["counters"]
+    assert d["jax.compile.requests"] == 3
+    assert d["jax.compile.in.step.that.compiles"] == 1
+    assert d["jax.compile.in.outer"] == 1
+    # counters, not spans: the trace holds only the two spans
+    assert [s.name for s in TRACER.spans] == ["outer", "step.that.compiles"]
+
+
+def test_spans_land_in_the_jax_profiler_trace(tmp_path):
+    """With tracing on, each span is also a host event of its name in a
+    ``jax.profiler`` trace, inside the enclosing profiler annotation and
+    nested as the spans are; a span left by an exception closes its event
+    too.  With tracing off nothing is annotated."""
+    import jax
+    from jax.profiler import ProfileData
+
+    TRACER.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("test.window"):
+        with TRACER.span("obs.outer"):
+            with TRACER.span("obs.inner"):
+                sum(range(1000))
+        with pytest.raises(ValueError):
+            with TRACER.span("obs.failing"):
+                raise ValueError("boom")
+    TRACER.disable()
+    with TRACER.span("obs.untraced"):
+        pass
+    jax.profiler.stop_trace()
+
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    events = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    events.setdefault(e.name, []).append((e.start_ns, e.start_ns + e.duration_ns))
+    assert "obs.untraced" not in events
+    (window,) = events["test.window"]
+    (outer,) = events["obs.outer"]
+    (inner,) = events["obs.inner"]
+    (failing,) = events["obs.failing"]
+    assert window[0] <= outer[0] <= inner[0] <= inner[1] <= outer[1] <= failing[0]
+    assert failing[1] <= window[1]
+    # the span's own wall time holds the annotation's
+    spans = {s.name: s for s in TRACER.spans}
+    assert (outer[1] - outer[0]) * 1e-9 <= spans["obs.outer"].wall_dur
 
 
 # ---------------------------------------------------------------------------
