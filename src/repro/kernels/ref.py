@@ -42,51 +42,74 @@ def population_makespan_ref(
 
     ``deadline`` (when given) adds one violation per task finishing past its
     deadline — deadlines are checked here because finish times only exist
-    inside the scheduling scan."""
-    T = durations.shape[0]
+    inside the scheduling scan.
+
+    The population is the minor axis, as in the Pallas kernel: one scan over
+    the tasks carries the finish times ``[T, P]``, and a task's predecessor
+    terms are whole rows that every candidate shares.  Nothing fetches single
+    elements by a candidate's node, which XLA:TPU does one element at a time
+    (about 12 ns each on a v5e): a candidate's link rates are the row
+    ``dtr.T[i]``, selected at the predecessors' nodes by a masked sum over
+    the node axis, and its duration, core count and feasibility are selected
+    the same way before the scan.  A masked sum of one value and zeros is
+    that value, so every number is the one an indexed read gives."""
+    T, N = durations.shape
     if node_cores is None:
         # padding entries are "never free" (+1e30); real cores start ≤ horizon
         node_cores = jnp.sum(init_free < 1e29, axis=1).astype(jnp.int32)
         node_cores = jnp.maximum(node_cores, 1)
+    a = assignments.astype(jnp.int32).T  # [T, P]
+    P = a.shape[1]
+    node_ids = jnp.arange(N, dtype=jnp.int32)
+    on_node = a[:, None, :] == node_ids[:, None]  # [T, N, P]
 
-    def eval_one(assignment):
-        def step(carry, j):
-            core_free, fin = carry
-            i = assignment[j]
-            ps = pred_matrix[j]
-            valid = ps >= 0
-            psafe = jnp.where(valid, ps, 0)
-            p_nodes = assignment[psafe]
-            rate = dtr[p_nodes, i]
-            transfer = jnp.where(p_nodes == i, 0.0, data[psafe] / rate)
-            ready_terms = jnp.where(valid, fin[psafe] + transfer, _NEG)
-            ready = jnp.maximum(release[j], jnp.max(ready_terms, initial=-1e30))
-            row = core_free[i]
-            # O(CMAX²) comparison-rank select — no sort, no gather/scatter;
-            # shares the primitive (and thus bit-exact values) with the
-            # Pallas kernel.
-            ranks = stable_ranks(row)
-            c = jnp.maximum(jnp.minimum(cores[j], node_cores[i]), 1)
-            kth = kth_from_ranks(row, ranks, c)
-            s = jnp.maximum(ready, kth)
-            f = s + durations[j, i]
-            row = update_from_ranks(row, ranks, c, f)
-            core_free = core_free.at[i].set(row)
-            fin = fin.at[j].set(f)
-            return (core_free, fin), None
+    def at_node(table):  # [T, N] -> table[t, a[t, p]] as [T, P]
+        return jnp.sum(jnp.where(on_node, table[:, :, None], 0), axis=1).astype(table.dtype)
 
-        # zeros shaped from an input, so under shard_map the carry varies
-        # over the same mesh axes as the state the scan writes into it
-        fin0 = jnp.zeros_like(durations[:, 0], dtype=jnp.float32)
-        (_, fin), _ = jax.lax.scan(step, (init_free, fin0), jnp.arange(T))
-        makespan = jnp.max(fin, initial=0.0)
-        feas = feasible[jnp.arange(T), assignment]
-        violations = jnp.sum(~feas).astype(jnp.float32)
-        if deadline is not None:
-            violations = violations + jnp.sum(fin > deadline).astype(jnp.float32)
-        return makespan, violations
+    dur = at_node(durations)
+    caps = at_node(jnp.broadcast_to(node_cores, (T, N)))
+    take = jnp.maximum(jnp.minimum(cores[:, None], caps), 1)
+    feas = jnp.any(on_node & feasible[:, :, None], axis=1)
+    valid = pred_matrix >= 0
+    psafe = jnp.where(valid, pred_matrix, 0)
+    pred_data = data[psafe]  # [T, MAXP]
+    rate_rows = dtr.T  # rate_rows[i, n] = dtr[n, i]
 
-    return jax.vmap(eval_one)(assignments)
+    def place(core_free, i, ready, c, d):  # one candidate's node
+        row = core_free[i]
+        # O(CMAX²) comparison-rank select — no sort, no gather/scatter;
+        # shares the primitive (and thus bit-exact values) with the Pallas
+        # kernel.
+        ranks = stable_ranks(row)
+        kth = kth_from_ranks(row, ranks, c)
+        f = jnp.maximum(ready, kth) + d
+        return core_free.at[i].set(update_from_ranks(row, ranks, c, f)), f
+
+    def step(carry, x):
+        core_free, fin = carry  # [P, N, Cmax], [T, P]
+        j, i, ps, ok, dp, r, d, c = x  # i, d, c [P]; ps, ok, dp [MAXP]
+        p_nodes = a[ps]  # [MAXP, P]
+        rows = rate_rows[i]  # [P, N]
+        hit = p_nodes[:, :, None] == node_ids  # [MAXP, P, N]
+        # where, not a product with a one-hot: 0 * inf would be NaN
+        rate = jnp.sum(jnp.where(hit, rows, 0.0), axis=-1)
+        transfer = jnp.where(p_nodes == i, 0.0, dp[:, None] / rate)
+        ready_terms = jnp.where(ok[:, None], fin[ps] + transfer, _NEG)
+        ready = jnp.maximum(r, jnp.max(ready_terms, axis=0, initial=-1e30))
+        core_free, f = jax.vmap(place)(core_free, i, ready, c, d)
+        return (core_free, fin.at[j].set(f)), None
+
+    # state shaped from the inputs, so under shard_map the carry varies over
+    # the same mesh axes as what the scan writes into it
+    fin0 = jnp.zeros_like(dur, dtype=jnp.float32)
+    core_free0 = jnp.broadcast_to(init_free, (P,) + init_free.shape)
+    xs = (jnp.arange(T), a, psafe, valid, pred_data, release, dur, take)
+    (_, fin), _ = jax.lax.scan(step, (core_free0, fin0), xs)
+    makespan = jnp.max(fin, axis=0, initial=0.0)
+    violations = jnp.sum(~feas, axis=0).astype(jnp.float32)
+    if deadline is not None:
+        violations = violations + jnp.sum(fin > deadline[:, None], axis=0).astype(jnp.float32)
+    return makespan, violations
 
 
 # -----------------------------------------------------------------------------

@@ -115,3 +115,17 @@ def test_ga_sweep_core_compiles_at_service_bucket(one_chip):
     # which the chip's profiler reports per operation
     assert re.search(r'fusion[.\w]* = .*op_name="[^"]*/fitness/', compiled.as_text())
     assert np.isfinite(compiled.memory_analysis().temp_size_in_bytes)
+
+
+def test_ga_sweep_core_compiles_at_table9_bucket(one_chip):
+    """The batched ``ga_sweep`` program of the Table IX benchmark cell: 8
+    instances at the 500x500 bucket, 64 candidates, 20 generations, the
+    evaluator's task step reading whole rows population-minor."""
+    B, bucket = 8, (512, 512, 64, 64)
+    arrays = _fitness_arrays(bucket, one_chip, batch=B)
+    logits = jax.ShapeDtypeStruct((B, bucket[0], bucket[1]), jnp.float32, sharding=one_chip)
+    keys = jax.ShapeDtypeStruct((B, 2), jnp.uint32, sharding=one_chip)
+    run = _ga_sweep_core("fixed", 64, 20, 4, 2)
+    compiled = run.lower(arrays, logits, keys, 1.0, 1.0, 0.08).compile()
+    assert re.search(r'fusion[.\w]* = .*op_name="[^"]*/fitness/', compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30  # fits one v5e
