@@ -45,7 +45,7 @@ def run() -> list[tuple]:
     us = _time(
         lambda a: population_makespan_pallas(
             a, jp["durations"], jp["cores"], jp["data"], jp["feasible"],
-            jp["release"], jp["pred_matrix"], jp["dtr"], jp["init_free"], tile=8,
+            jp["release"], jp["pred_rows"], jp["dtr"], jp["init_free"], tile=8,
         ),
         small, iters=2, warmup=1,
     )

@@ -204,7 +204,7 @@ def phase_table9(seed: int, sizes: dict, compiles: Compiles) -> None:
         a = pack(problem, pad=False).device_arrays()
         mk["pallas-streamed"] = np.asarray(population_makespan_pallas(
             pop.astype(np.int32), a["durations"], a["cores"], a["data"], a["feasible"],
-            a["release"], a["pred_matrix"], a["dtr"], a["init_free"], stream=True,
+            a["release"], a["pred_rows"], a["dtr"], a["init_free"], stream=True,
         )[0])
         for engine in ("jax", "pallas", "pallas-streamed"):
             diff = int(np.sum(mk[engine] != mk["oracle"]))

@@ -66,6 +66,7 @@ from repro.core.system_model import System, mri_system, synthetic_system
 from repro.core.workload_model import (
     Workload,
     constraints_from_json,
+    montage_workflow,
     mri_w1,
     mri_w2,
     mri_workload,
@@ -393,6 +394,16 @@ def _layered(coords: Mapping[str, Any]) -> Workload:
             ),
         )
     )
+
+
+@_family("montage")
+def _montage(coords: Mapping[str, Any]) -> Workload:
+    rows, cols = coords.get("rows"), coords.get("cols")
+    if rows is None or cols is None:
+        raise ValueError("family 'montage' needs 'rows' and 'cols' coordinates")
+    rows, cols = int(rows), int(cols)
+    return Workload((montage_workflow(rows, cols, seed=int(coords.get("seed", 0)),
+                                      name=f"M{rows}x{cols}"),))
 
 
 @_family("mri")

@@ -49,6 +49,7 @@ from repro.core.workload_model import (
     Workflow,
     Workload,
     build_problem,
+    montage_workflow,
     mri_w1,
     mri_w2,
     mri_workload,
@@ -93,6 +94,7 @@ __all__ = [
     "evaluate_population_batch",
     "make_batched_fitness_fn",
     "make_system",
+    "montage_workflow",
     "mri_system",
     "mri_w1",
     "mri_w2",

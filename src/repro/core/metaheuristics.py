@@ -30,7 +30,7 @@ from repro.core.evaluator import (
     evaluate_assignment,
 )
 from repro.core.workload_model import ScheduleProblem
-from repro.engine.packed import stack_packed
+from repro.engine.packed import common_bucket, pack, stack_device
 
 _NEG = -1e30
 
@@ -49,21 +49,28 @@ class MHResult:
     history: np.ndarray  # best objective per iteration
 
 
-def _safe_feasible(problem: ScheduleProblem) -> np.ndarray:
-    """Feasibility mask with at least one "samplable" node per task even if
-    infeasible (the fitness penalty then dominates and the candidate dies
-    off)."""
-    safe = problem.feasible.copy()
-    dead = ~safe.any(axis=1)
-    if dead.any():
-        safe[dead, 0] = True
-    return safe
-
-
 def _mask_logits(problem: ScheduleProblem):
     import jax.numpy as jnp
 
-    return jnp.where(jnp.asarray(_safe_feasible(problem)), 0.0, _NEG)
+    return _sampling_logits()(jnp.asarray(problem.feasible))
+
+
+@functools.lru_cache(maxsize=None)
+def _sampling_logits() -> Callable:
+    """``feasible [..., T, N] -> logits [..., T, N]`` on the device: 0 where
+    a task may run, ``_NEG`` elsewhere.  A task that no node can run samples
+    node 0 all the same (the fitness penalty then dominates and the
+    candidate dies off); a padded task of a stack is feasible on node 0
+    alone, so it pins there."""
+    import jax
+    import jax.numpy as jnp
+
+    def logits(feasible):
+        dead = ~feasible.any(axis=-1)
+        safe = feasible.at[..., 0].set(feasible[..., 0] | dead)
+        return jnp.where(safe, jnp.float32(0.0), jnp.float32(_NEG))
+
+    return jax.jit(logits)
 
 
 def _finish(
@@ -283,12 +290,14 @@ def ga_sweep(
                 problems, weights, pop_size, generations, tournament, elite, seed, shard
             )
             prepare.set(h2d_bytes=h2d_bytes)
-        call.set(shards=shards, bucket="x".join(str(x) for x in bucket))
+        call.set(shards=shards, bucket="x".join(str(x) for x in bucket),
+                 rows=bucket[4], tasks=bucket[0])
         with obs.TRACER.span("mh.ga_sweep.device", cat="engine"):
             best, hist = run(*inputs, weights.alpha, weights.beta, mutation_rate)
             best, hist = np.asarray(best)[:B], np.asarray(hist)[:B]
         obs.METRICS.counter("mh.ga_sweep.instances").inc(B)
         obs.METRICS.gauge("mh.ga_sweep.shards").set(shards)
+        obs.METRICS.gauge("engine.pred_rows").set(bucket[4])
         return [
             _finish(
                 problem,
@@ -308,7 +317,8 @@ def _ga_sweep_inputs(problems, weights, pop_size, generations, tournament, elite
     count, the stacked instances, the logits and the PRNG keys, on the
     device.  Returns ``(program, (arrays, logits, keys), shards, bucket,
     h2d_bytes)``; ``h2d_bytes`` counts what this call copies to the device
-    (a sharded stack stays resident in the pack LRU and is not copied)."""
+    (an instance already on the device, or a sharded stack resident in the
+    pack LRU, is not copied)."""
     import jax
     import jax.numpy as jnp
 
@@ -326,16 +336,14 @@ def _ga_sweep_inputs(problems, weights, pop_size, generations, tournament, elite
         arrays, bucket, Bp = stack.arrays, stack.bucket, stack.padded
         h2d_bytes = 0
     else:
-        arrays, bucket = stack_packed(problems)
+        bucket = common_bucket(problems)
+        packed = [pack(p, bucket) for p in problems]
+        fresh = {id(pp): pp for pp in packed if not pp.on_device}
+        h2d_bytes = sum(pp.nbytes for pp in fresh.values())
+        arrays = stack_device(packed)
         Bp = B
-        h2d_bytes = sum(a.nbytes for a in arrays.values())
-    Tb, Nb = bucket[0], bucket[1]
-    logits = np.full((Bp, Tb, Nb), _NEG, dtype=np.float32)
-    for b, problem in enumerate(problems):
-        mask = _safe_feasible(problem)
-        logits[b, : problem.num_tasks, : problem.num_nodes][mask] = 0.0
-        logits[b, problem.num_tasks :, 0] = 0.0  # padded tasks pin to node 0
-    logits[B:] = logits[0]  # pad-to-shard-multiple rows replay instance 0
+    # pad-to-shard-multiple rows replicate instance 0, and so do its logits
+    logits = _sampling_logits()(arrays["feasible"])
     constrained = any(p.has_constraints for p in problems)
     run = _ga_sweep_core(
         weights.usage_mode, pop_size, generations, tournament, elite, shards, constrained
@@ -343,13 +351,11 @@ def _ga_sweep_inputs(problems, weights, pop_size, generations, tournament, elite
     keys = np.asarray(jax.random.split(jax.random.PRNGKey(seed), B))
     keys = np.concatenate([keys, np.repeat(keys[:1], Bp - B, axis=0)])
     if shards > 1:
-        sharding = shard_mod.instance_sharding(shards)
-        logits_dev = jax.device_put(logits, sharding)
-        keys_dev = jax.device_put(keys, sharding)
+        keys_dev = jax.device_put(keys, shard_mod.instance_sharding(shards))
     else:
-        logits_dev, keys_dev = jnp.asarray(logits), jnp.asarray(keys)
-    h2d_bytes += logits.nbytes + keys.nbytes
-    return run, (arrays, logits_dev, keys_dev), shards, bucket, h2d_bytes
+        keys_dev = jnp.asarray(keys)
+    h2d_bytes += keys.nbytes
+    return run, (arrays, logits, keys_dev), shards, bucket, h2d_bytes
 
 
 # -----------------------------------------------------------------------------

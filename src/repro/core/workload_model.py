@@ -474,7 +474,8 @@ def _hash_into(h: "hashlib._Hash", obj: Any) -> None:
             tag, arr = b"aI", np.ascontiguousarray(obj, dtype=np.int64)
         else:
             tag, arr = b"aF", np.ascontiguousarray(obj, dtype=np.float64)
-        h.update(tag + str(obj.shape).encode() + arr.tobytes())
+        h.update(tag + str(obj.shape).encode())
+        h.update(arr)  # the buffer itself: the digest of the bytes, no copy
     elif isinstance(obj, Mapping):
         h.update(b"{")
         for k in sorted(obj, key=str):
@@ -517,7 +518,16 @@ def problem_fingerprint(problem: "ScheduleProblem") -> str:
     problem changes the key and any byte-identical rebuild reuses it.
 
     Constraint arrays enter the hash only when present, so every
-    pre-constraint fingerprint (and any cache keyed on one) is unchanged."""
+    pre-constraint fingerprint (and any cache keyed on one) is unchanged.
+
+    Computed once per problem and kept on it: a sweep that meets the same
+    problems call after call does not hash them again.  The arrays it covers
+    become read-only then, so an in-place change raises instead of leaving a
+    stale key (change a problem before its first fingerprint, or build a new
+    one)."""
+    cached = problem.__dict__.get("_fingerprint")
+    if cached is not None:
+        return cached
     payload: dict[str, Any] = {
         "node_cores": problem.node_cores,
         "dtr": problem.dtr,
@@ -538,7 +548,11 @@ def problem_fingerprint(problem: "ScheduleProblem") -> str:
         payload["cost_rate"] = problem.cost_rate
     if problem.budget is not None:
         payload["budget"] = problem.budget
-    return canonical_hash(payload)
+    for value in payload.values():
+        if isinstance(value, np.ndarray):
+            value.setflags(write=False)
+    problem._fingerprint = canonical_hash(payload)
+    return problem._fingerprint
 
 
 # -----------------------------------------------------------------------------
@@ -686,6 +700,81 @@ def random_layered_workflow(
                     deps=tuple(deps),
                 )
             )
+    return Workflow(name=name, tasks=tuple(tasks))
+
+
+#: Montage task types: ``(work at speed 1 in s, output size in MB)``, after
+#: the per-job profile of Montage in Juve et al., "Characterizing and
+#: Profiling Scientific Workflows" (FGCS 29(3), 2013, Table 2), rounded.
+#: mConcatFit, mBgModel, mAdd and mShrink are long; mProjectPP, mDiffFit and
+#: mBackground are short, and mProjectPP and mBackground write whole images.
+MONTAGE_TYPES: dict[str, tuple[float, float]] = {
+    "mProjectPP": (1.73, 8.09),
+    "mDiffFit": (0.66, 0.64),
+    "mConcatFit": (143.26, 1.18),
+    "mBgModel": (384.49, 0.10),
+    "mBackground": (1.72, 8.09),
+    "mImgtbl": (2.78, 0.12),
+    "mAdd": (282.37, 775.45),
+    "mShrink": (66.10, 0.49),
+    "mJPEG": (0.64, 0.39),
+}
+#: σ of the seeded lognormal factor on each task's work and output size
+MONTAGE_JITTER = 0.2
+
+
+def montage_overlaps(rows: int, cols: int) -> list[tuple[int, int]]:
+    """The overlapping image pairs of a ``rows`` x ``cols`` mosaic: every
+    8-neighbour pair of the grid (image ``r * cols + c``), horizontal pairs
+    first, then vertical, then the two diagonals."""
+    def at(r: int, c: int) -> int:
+        return r * cols + c
+
+    pairs = [(at(r, c), at(r, c + 1)) for r in range(rows) for c in range(cols - 1)]
+    pairs += [(at(r, c), at(r + 1, c)) for r in range(rows - 1) for c in range(cols)]
+    pairs += [(at(r, c), at(r + 1, c + 1)) for r in range(rows - 1) for c in range(cols - 1)]
+    pairs += [(at(r, c + 1), at(r + 1, c)) for r in range(rows - 1) for c in range(cols - 1)]
+    return pairs
+
+
+def montage_workflow(rows: int, cols: int, *, seed: int = 0, name: str = "Wm") -> Workflow:
+    """A Montage sky mosaic of ``rows`` x ``cols`` input images, with the
+    structure of Bharathi et al. (WORKS 2008) and Juve et al. (FGCS 2013):
+
+    ``mProjectPP`` per image; ``mDiffFit`` per overlapping pair, on the two
+    projections; ``mConcatFit`` on every fit; ``mBgModel``; ``mBackground``
+    per image, on the model and its projection; ``mImgtbl`` on every
+    corrected image; ``mAdd`` on the table and every corrected image;
+    ``mShrink``; ``mJPEG``.  Tasks are single-core and need feature F1; each
+    type's work and output size (:data:`MONTAGE_TYPES`) carry a seeded
+    lognormal factor of σ :data:`MONTAGE_JITTER`, drawn per task in task
+    order (work, then data)."""
+    if rows < 1 or cols < 1 or rows * cols < 2:
+        raise ValueError(f"a mosaic needs at least two images, got {rows}x{cols}")
+    rng = np.random.default_rng(seed)
+    n = rows * cols
+    pairs = montage_overlaps(rows, cols)
+    proj = [f"mProjectPP_{k}" for k in range(n)]
+    fits = [f"mDiffFit_{k}" for k in range(len(pairs))]
+    back = [f"mBackground_{k}" for k in range(n)]
+    layout: list[tuple[str, str, tuple[str, ...]]] = [
+        (p, "mProjectPP", ()) for p in proj
+    ]
+    layout += [(f, "mDiffFit", (proj[a], proj[b])) for f, (a, b) in zip(fits, pairs)]
+    layout += [("mConcatFit", "mConcatFit", tuple(fits)),
+               ("mBgModel", "mBgModel", ("mConcatFit",))]
+    layout += [(b, "mBackground", ("mBgModel", p)) for b, p in zip(back, proj)]
+    layout += [("mImgtbl", "mImgtbl", tuple(back)),
+               ("mAdd", "mAdd", ("mImgtbl",) + tuple(back)),
+               ("mShrink", "mShrink", ("mAdd",)),
+               ("mJPEG", "mJPEG", ("mShrink",))]
+    tasks = []
+    for task_name, kind, deps in layout:
+        work, data = MONTAGE_TYPES[kind]
+        jitter = np.exp(MONTAGE_JITTER * rng.standard_normal(2))
+        tasks.append(Task(name=task_name, cores=1.0, data=float(data * jitter[1]),
+                          features=frozenset({"F1"}), work=float(work * jitter[0]),
+                          deps=deps))
     return Workflow(name=name, tasks=tuple(tasks))
 
 

@@ -107,11 +107,13 @@ def population_fitness_from_arrays(
             data=arrays["data"],
             feasible=arrays["feasible"],
             release=arrays["release"],
-            pred_matrix=arrays["pred_matrix"],
+            pred_rows=arrays["pred_rows"],
             dtr=arrays["dtr"],
             init_free=arrays["init_free"],
             node_cores=arrays["node_cores"],
             deadline=arrays["deadline"] if constrained else None,
+            row_task=arrays["row_task"],
+            row_last=arrays["row_last"],
         )
         if constrained:
             violations = violations + _budget_overage(arrays, assignments)
@@ -511,10 +513,12 @@ class PallasEngine(ScheduleEngine):
                 data=arrays["data"],
                 feasible=arrays["feasible"],
                 release=arrays["release"],
-                pred_matrix=arrays["pred_matrix"],
+                pred_rows=arrays["pred_rows"],
                 dtr=arrays["dtr"],
                 init_free=arrays["init_free"],
                 deadline=arrays["deadline"] if packed.constrained else None,
+                row_task=arrays["row_task"],
+                row_last=arrays["row_last"],
                 force=True,
             )
             # identical penalty expression to population_fitness_from_arrays —

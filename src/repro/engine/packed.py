@@ -26,6 +26,7 @@ cached on the instance, which the LRU keeps alive).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 from collections import OrderedDict
 from typing import Any, Callable, Sequence
@@ -44,7 +45,9 @@ FITNESS_ARRAY_KEYS = (
     "data",
     "feasible",
     "release",
-    "pred_matrix",
+    "pred_rows",
+    "row_task",
+    "row_last",
     "dtr",
     "init_free",
     "node_cores",
@@ -58,7 +61,16 @@ FITNESS_ARRAY_KEYS = (
     "wf_budget",
 )
 
-Bucket = tuple[int, int, int, int]
+#: ``(T, N, CMAX, K, S)``: tasks, nodes, the core window, and the
+#: predecessor rows — ``S`` rows of ``K`` slots each (see :func:`row_width`)
+Bucket = tuple[int, int, int, int, int]
+
+#: the widest predecessor row: a bucket whose largest in-degree fits one row
+#: of this width keeps one row per task (``K`` = that in-degree, ``S = T``)
+ROW_WIDTH_MAX = 64
+#: the row width of a bucket with a wider join: the join spreads over
+#: ``ceil(d / K)`` rows, and every row of the scan pays for ``K`` slots
+JOIN_ROW_WIDTH = 16
 
 
 def _round_up_pow2(x: int, floor: int = 4) -> int:
@@ -75,32 +87,50 @@ def _cmax_of(problem: ScheduleProblem, core_cap: int | None) -> int:
     return max(cmax, int(problem.cores.max(initial=1)), 1)
 
 
+def row_width(maxp: int) -> int:
+    """Predecessor slots per row for a bucket whose largest in-degree is
+    ``maxp``: ``maxp`` itself up to :data:`ROW_WIDTH_MAX`, else
+    :data:`JOIN_ROW_WIDTH`."""
+    return maxp if maxp <= ROW_WIDTH_MAX else JOIN_ROW_WIDTH
+
+
+def task_rows(problem: ScheduleProblem, width: int) -> np.ndarray:
+    """Rows each task takes at ``width`` slots a row: ``max(1, ceil(d / width))``
+    for in-degree ``d``."""
+    indptr, _ = problem.pred_csr
+    return np.maximum(1, -(-np.diff(indptr) // width))
+
+
 def exact_bucket(problem: ScheduleProblem, core_cap: int | None = None) -> Bucket:
-    """The problem's own shapes ``(T, N, CMAX, MAXP)`` — no padding."""
+    """The problem's own shapes ``(T, N, CMAX, K, S)`` — no padding."""
+    width = row_width(max(int(problem.pred_matrix.shape[1]), 1))
     return (
         problem.num_tasks,
         problem.num_nodes,
         _cmax_of(problem, core_cap),
-        max(int(problem.pred_matrix.shape[1]), 1),
+        width,
+        int(task_rows(problem, width).sum()),
     )
+
+
+def common_bucket(problems: Sequence[ScheduleProblem], core_cap: int | None = None) -> Bucket:
+    """One shape bucket ``(T, N, CMAX, K, S)`` for every problem in the list:
+    ``T``, ``N``, ``CMAX`` and the largest in-degree rounded up to powers of
+    two, so unequal instances share compiled programs; ``K`` from that
+    in-degree (:func:`row_width`); ``S = T`` when every task fits one row,
+    else ``T`` plus the most rows any instance's joins add, rounded up to a
+    power of two."""
+    exact = [exact_bucket(p, core_cap) for p in problems]
+    t, n, cmax = (max(_round_up_pow2(b[d]) for b in exact) for d in range(3))
+    maxp = max(_round_up_pow2(p.pred_matrix.shape[1], floor=1) for p in problems)
+    width = row_width(maxp)
+    extra = max(int(task_rows(p, width).sum()) - p.num_tasks for p in problems)
+    return (t, n, cmax, width, t + (_round_up_pow2(extra, floor=8) if extra else 0))
 
 
 def bucket_of(problem: ScheduleProblem, core_cap: int | None = None) -> Bucket:
-    """Shape bucket ``(T, N, CMAX, MAXP)`` for this problem — each dim rounded
-    to the next power of two so unequal instances share compiled programs."""
-    t, n, cmax, maxp = exact_bucket(problem, core_cap)
-    return (
-        _round_up_pow2(t),
-        _round_up_pow2(n),
-        _round_up_pow2(cmax),
-        _round_up_pow2(maxp, floor=1),
-    )
-
-
-def common_bucket(problems: Sequence[ScheduleProblem]) -> Bucket:
-    """Elementwise-max bucket covering every problem in the list."""
-    buckets = [bucket_of(p) for p in problems]
-    return tuple(max(b[d] for b in buckets) for d in range(4))  # type: ignore[return-value]
+    """:func:`common_bucket` of this problem alone."""
+    return common_bucket([problem], core_cap)
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -116,7 +146,9 @@ class PackedProblem:
     data: np.ndarray  # [Tb] f32
     feasible: np.ndarray  # [Tb, Nb] bool
     release: np.ndarray  # [Tb] f32
-    pred_matrix: np.ndarray  # [Tb, Pb] i32, -1 padded
+    pred_rows: np.ndarray  # [Sb, Kb] i32 predecessors of row_task, -1 padded
+    row_task: np.ndarray  # [Sb] i32 task of each row (contiguous, task order)
+    row_last: np.ndarray  # [Sb] bool the task's last row, which places it
     dtr: np.ndarray  # [Nb, Nb] f32, +INF for dead links
     init_free: np.ndarray  # [Nb, Cb] f32, +INF core padding
     node_cores: np.ndarray  # [Nb] i32
@@ -147,6 +179,11 @@ class PackedProblem:
         once built, occupy roughly the same again)."""
         return sum(getattr(self, k).nbytes for k in FITNESS_ARRAY_KEYS)
 
+    @property
+    def on_device(self) -> bool:
+        """Whether :meth:`device_arrays` has transferred the arrays yet."""
+        return self._device is not None
+
     def device_arrays(self) -> dict[str, Any]:
         """jnp copies of :meth:`numpy_arrays`, transferred once and cached."""
         if self._device is None:
@@ -166,11 +203,12 @@ def _build(
     fingerprint: str | None,
     core_cap: int | None = None,
 ) -> PackedProblem:
-    Tb, Nb, Cb, Pb = bucket
+    Tb, Nb, Cb, Kb, Sb = bucket
     T, N = problem.num_tasks, problem.num_nodes
-    maxp = problem.pred_matrix.shape[1]
-    if T > Tb or N > Nb or maxp > Pb:
-        raise ValueError(f"problem {T}x{N} (maxp={maxp}) exceeds bucket {bucket}")
+    per_task = task_rows(problem, Kb)
+    rows = int(per_task.sum()) + Tb - T  # a padded task takes one row
+    if T > Tb or N > Nb or rows > Sb:
+        raise ValueError(f"problem {T}x{N} ({rows} rows of {Kb}) exceeds bucket {bucket}")
     caps = problem.node_cores.astype(np.int64)
     if int(problem.cores.max(initial=1)) > Cb:
         raise ValueError(f"task core request exceeds bucket cmax {Cb}")
@@ -186,8 +224,7 @@ def _build(
     feasible[T:, 0] = True  # padded tasks live on node 0
     release = np.zeros(Tb, np.float32)
     release[:T] = problem.release
-    pred_matrix = -np.ones((Tb, Pb), np.int32)
-    pred_matrix[:T, :maxp] = problem.pred_matrix
+    pred_rows, row_task, row_last = _pred_rows(problem, per_task, Tb, Kb, Sb)
     dtr = np.ones((Nb, Nb), np.float32)
     dtr[:N, :N] = np.where(np.isfinite(problem.dtr), problem.dtr, _INF)
     init_free = np.full((Nb, Cb), _INF, np.float32)
@@ -218,7 +255,9 @@ def _build(
         "data": data,
         "feasible": feasible,
         "release": release,
-        "pred_matrix": pred_matrix,
+        "pred_rows": pred_rows,
+        "row_task": row_task,
+        "row_last": row_last,
         "dtr": dtr,
         "init_free": init_free,
         "node_cores": node_cores,
@@ -240,6 +279,26 @@ def _build(
         constrained=problem.has_constraints,
         **arrays,
     )
+
+
+def _pred_rows(problem: ScheduleProblem, per_task: np.ndarray, Tb: int, Kb: int, Sb: int):
+    """The predecessor rows of a bucket: each real task's ``per_task`` rows
+    in task order, its q-th predecessor in row ``q // Kb``, slot ``q % Kb``;
+    then one empty row per padded task; then filler rows, which are no task's
+    last row and so change nothing."""
+    indptr, preds = problem.pred_csr
+    T, indeg = problem.num_tasks, np.diff(indptr)
+    first = np.concatenate([[0], np.cumsum(per_task)])  # each task's first row
+    pred_rows = -np.ones((Sb, Kb), np.int32)
+    q = np.arange(len(preds)) - np.repeat(indptr[:-1], indeg)
+    pred_rows[np.repeat(first[:-1], indeg) + q // Kb, q % Kb] = preds
+    row_task = np.full(Sb, Tb - 1, np.int32)
+    row_task[: first[-1]] = np.repeat(np.arange(T), per_task)
+    row_task[first[-1] : first[-1] + Tb - T] = np.arange(T, Tb)
+    row_last = np.zeros(Sb, bool)
+    row_last[first[1:] - 1] = True
+    row_last[first[-1] : first[-1] + Tb - T] = True
+    return pred_rows, row_task, row_last
 
 
 @dataclasses.dataclass
@@ -411,19 +470,32 @@ def stack_packed(
     problems: Sequence[ScheduleProblem], bucket: Bucket | None = None
 ) -> tuple[dict[str, Any], Bucket]:
     """Stack padded instances along a leading batch axis → jnp array dict
-    (one shared bucket, one device transfer for the stack).
+    (one shared bucket; see :func:`stack_device`).
 
     Single-device layout; :func:`repro.engine.shard.stack_packed_sharded`
     is the multi-device sibling that stripes the same leading axis across
     the local mesh with pad-to-shard-multiple semantics."""
+    bucket = common_bucket(problems) if bucket is None else bucket
+    return stack_device([pack(p, bucket) for p in problems]), bucket
+
+
+def stack_device(packed: Sequence[PackedProblem]) -> dict[str, Any]:
+    """Stack packed instances of one bucket on the device: each instance's
+    arrays cross to the device once (:meth:`PackedProblem.device_arrays`,
+    kept alive by the pack LRU), and a family that meets again is stacked
+    there, in one dispatch, without a host copy."""
+    return _stack_program()([pp.device_arrays() for pp in packed])
+
+
+@functools.lru_cache(maxsize=None)
+def _stack_program() -> Callable[[list[dict[str, Any]]], dict[str, Any]]:
+    import jax
     import jax.numpy as jnp
 
-    bucket = common_bucket(problems) if bucket is None else bucket
-    packed = [pack(p, bucket) for p in problems]
-    return (
-        {k: jnp.asarray(np.stack([pp.numpy_arrays()[k] for pp in packed])) for k in FITNESS_ARRAY_KEYS},
-        bucket,
-    )
+    def stack(members):
+        return {k: jnp.stack([arrays[k] for arrays in members]) for k in FITNESS_ARRAY_KEYS}
+
+    return jax.jit(stack)
 
 
 # ---- legacy surfaces (served through repro.core.evaluator's warning shims) ---

@@ -24,11 +24,21 @@ re-deriving its own core bookkeeping:
 
 from __future__ import annotations
 
+import bisect
+
 import numpy as np
 
 from repro.core.workload_model import ScheduleProblem
 
 _INF = 1e30  # finite stand-in for +inf (matches the device evaluators)
+
+
+def _scalar_type(dtype):
+    """The scalar a host loop computes ``dtype`` arithmetic in: Python's
+    float for float64 (the same IEEE operations, without numpy's per-scalar
+    overhead), else numpy's scalar of that dtype."""
+    dtype = np.dtype(dtype)
+    return float if dtype == np.float64 else dtype.type
 
 
 def commit_sorted(row: np.ndarray, c: int, fill) -> np.ndarray:
@@ -73,7 +83,8 @@ class CoreSim:
         if exact:
             self.cmax = int(max(caps.max(initial=1), problem.cores.max(initial=1), 1))
             self.width = np.maximum(caps, 1)
-            self._rows = [np.zeros(max(int(c), 1), dtype=dtype) for c in caps]
+            zero = _scalar_type(dtype)(0.0)
+            self._rows = [[zero] * max(int(c), 1) for c in caps]
         else:
             widest = int(min(caps.max(initial=1), 512))
             self.cmax = int(max(widest, problem.cores.max(initial=1), 1))
@@ -95,14 +106,19 @@ class CoreSim:
         core)."""
         if self.exact:
             row = self._rows[i]
-            return row[max(1, min(c, row.size)) - 1]
+            return row[max(1, min(c, len(row))) - 1]
         c = max(1, min(c, int(self.width[i])))
         return self.free[i, c - 1]
 
     def commit(self, i: int, c: int, finish) -> None:
         if self.exact:
+            # the list twin of commit_sorted: rows are short Python lists of
+            # scalars, where numpy's per-call overhead would dominate
             row = self._rows[i]
-            self._rows[i] = commit_sorted(row, max(1, min(c, row.size)), finish)
+            c = max(1, min(c, len(row)))
+            rest = row[c:]
+            pos = bisect.bisect_left(rest, finish)
+            self._rows[i] = rest[:pos] + [finish] * c + rest[pos:]
         else:
             c = max(1, min(c, self.cmax))
             self.free[i] = commit_sorted(self.free[i], c, finish)
@@ -153,6 +169,7 @@ def run_schedule(
     """
     assignment = np.asarray(assignment, dtype=np.int64)
     T = problem.num_tasks
+    tasks = np.arange(T)
     caps = problem.node_cores.astype(np.int64)
     durations = problem.durations
     if speed_factors is not None:
@@ -161,41 +178,47 @@ def run_schedule(
             durations = durations / np.maximum(factors, 1e-9)[None, :]
     durations = durations.astype(dtype, copy=False)
     data = problem.data.astype(dtype, copy=False)
-    release = problem.release.astype(dtype, copy=False)
     dtr = problem.dtr.astype(dtype, copy=False)
     indptr, indices = problem.pred_csr
-    sim = CoreSim(problem, dtype=dtype, exact=True)
-    start = np.zeros(T, dtype=dtype)
-    finish = np.zeros(T, dtype=dtype)
     inf = dtype(_INF) if dtype is not np.float64 else _INF
-    violations = 0
 
+    # the assignment is fixed, so every edge's transfer time, every task's
+    # duration and core request are known before the walk: only the finish
+    # times and the core state are sequential
+    ips = assignment[indices]
+    idst = assignment[np.repeat(tasks, np.diff(indptr))]
+    rates = dtr[ips, idst]
+    ok = np.isfinite(rates) & (rates > 0)
+    with np.errstate(divide="ignore", invalid="ignore"):
+        transfer = np.where(
+            ips == idst, dtype(0.0), np.where(ok, data[indices] / np.where(ok, rates, 1), inf)
+        )
+    dur = durations[tasks, assignment]
+    if jitter_mults is not None:
+        dur = dur * jitter_mults[:T]
+    violations = int(np.count_nonzero(~problem.feasible[tasks, assignment]))
+    need = np.maximum(np.minimum(problem.cores, caps[assignment]), 1).astype(np.int64)
+
+    scalar = _scalar_type(dtype)
+    values = np.ndarray.tolist if scalar is float else list
+    release, dur, transfer = (values(a) for a in
+                              (problem.release.astype(dtype, copy=False), dur, transfer))
+    ptr, preds, nodes, need = indptr.tolist(), indices.tolist(), assignment.tolist(), need.tolist()
+    sim = CoreSim(problem, dtype=dtype, exact=True)
+    start, finish = [scalar(0.0)] * T, [scalar(0.0)] * T
     for j in range(T):
-        i = int(assignment[j])
-        if not problem.feasible[j, i]:
-            violations += 1
+        i = nodes[j]
         ready = release[j]
-        lo, hi = indptr[j], indptr[j + 1]
-        if hi > lo:
-            ps = indices[lo:hi]
-            ips = assignment[ps]
-            rates = dtr[ips, i]
-            ok = np.isfinite(rates) & (rates > 0)
-            with np.errstate(divide="ignore", invalid="ignore"):
-                transfer = np.where(
-                    ips == i, dtype(0.0), np.where(ok, data[ps] / np.where(ok, rates, 1), inf)
-                )
-            ready = np.maximum(ready, (finish[ps] + transfer).max())
-        c = int(max(1, min(problem.cores[j], caps[i])))
-        kth = sim.kth_free(i, c)
-        s = np.maximum(ready, kth)
-        dur = durations[j, i]
-        if jitter_mults is not None:
-            dur = dur * jitter_mults[j]
-        f = s + dur
-        sim.commit(i, c, f)
+        for e in range(ptr[j], ptr[j + 1]):
+            v = finish[preds[e]] + transfer[e]
+            if v > ready:
+                ready = v
+        kth = sim.kth_free(i, need[j])
+        s = ready if ready >= kth else kth
+        f = scalar(s + dur[j])
+        sim.commit(i, need[j], f)
         start[j], finish[j] = s, f
-    return start, finish, violations
+    return np.array(start, dtype=dtype), np.array(finish, dtype=dtype), violations
 
 
 def accumulate_occupancy(

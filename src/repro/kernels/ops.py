@@ -10,7 +10,8 @@ Selection policy:
   lowers (Pallas does not lower to the XLA:CPU backend), and its FLOPs match
   the kernel contract, so the roofline terms are representative.
 * ``population_makespan`` falls back to the oracle whenever the instance
-  exceeds the kernel's VMEM/SMEM envelope.  The ``engine.traced.pallas`` /
+  exceeds the kernel's VMEM/SMEM envelope, or a join spreads over more
+  than one predecessor row.  The ``engine.traced.pallas`` /
   ``engine.traced.ref`` counters count how often each path was *traced*:
   under ``jit`` that is once per compiled program, not once per call.
 """
@@ -70,31 +71,35 @@ def population_makespan(
     data: jax.Array,
     feasible: jax.Array,
     release: jax.Array,
-    pred_matrix: jax.Array,
+    pred_rows: jax.Array,
     dtr: jax.Array,
     init_free: jax.Array,
     deadline: jax.Array | None = None,
+    row_task: jax.Array | None = None,
+    row_last: jax.Array | None = None,
     force: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Dispatch: the Pallas kernel (resident → streamed) when enabled and
     within its envelope, else the jnp oracle.  ``force=True`` routes through
     the kernel regardless of the global config (the ``pallas`` engine
-    backend) — the envelope fallback still applies.  ``deadline`` ([T]
-    latest finish, 1e30 = unconstrained) folds late tasks into the
-    violation count."""
+    backend) — the envelope fallback still applies.  The kernel reads one
+    predecessor row per task, so a bucket whose joins take more rows than
+    it has tasks is outside its envelope.  ``deadline`` ([T] latest finish,
+    1e30 = unconstrained) folds late tasks into the violation count."""
     T = assignments.shape[1]
     N = durations.shape[1]
     cmax = init_free.shape[1]
-    maxp = pred_matrix.shape[1]
+    rows, maxp = pred_rows.shape
     if deadline is None:
         deadline = jnp.full((T,), 1e30, dtype=jnp.float32)
-    stream = _makespan_mode(T, N, cmax, maxp) if force or _CONFIG.use_pallas else None
+    use = (force or _CONFIG.use_pallas) and rows == T
+    stream = _makespan_mode(T, N, cmax, maxp) if use else None
     # trace-time counts: under jit they record per compilation, not per
     # executed call
     if stream is not None:
         obs.METRICS.counter("engine.traced.pallas").inc()
         return makespan.population_makespan_pallas(
-            assignments, durations, cores, data, feasible, release, pred_matrix,
+            assignments, durations, cores, data, feasible, release, pred_rows,
             dtr, init_free, deadline, stream=stream,
         )
     obs.METRICS.counter("engine.traced.ref").inc()
@@ -105,10 +110,12 @@ def population_makespan(
         data=data,
         feasible=feasible,
         release=release,
-        pred_matrix=pred_matrix,
+        pred_rows=pred_rows,
         dtr=dtr,
         init_free=init_free,
         deadline=deadline,
+        row_task=row_task,
+        row_last=row_last,
     )
 
 

@@ -30,11 +30,13 @@ def population_makespan_ref(
     data: jax.Array,  # [T] f32 output sizes
     feasible: jax.Array,  # [T, N] bool
     release: jax.Array,  # [T] f32
-    pred_matrix: jax.Array,  # [T, maxP] int32, -1 padded
+    pred_rows: jax.Array,  # [S, K] int32 predecessors of row_task, -1 padded
     dtr: jax.Array,  # [N, N] f32 (large finite instead of inf on diag)
     init_free: jax.Array,  # [N, Cmax] f32 (inf-padded beyond node cores)
     node_cores: jax.Array | None = None,  # [N] int32
     deadline: jax.Array | None = None,  # [T] f32 latest finish (1e30 = none)
+    row_task: jax.Array | None = None,  # [S] int32 task of each row
+    row_last: jax.Array | None = None,  # [S] bool the task's last row
 ) -> tuple[jax.Array, jax.Array]:
     """Capacity-aware core-granular list scheduling (see
     ``repro.core.evaluator`` for the semantics).  Returns
@@ -44,8 +46,17 @@ def population_makespan_ref(
     deadline — deadlines are checked here because finish times only exist
     inside the scheduling scan.
 
+    Predecessors come in rows of ``K`` slots: a task's rows are contiguous
+    and in task order, and each folds the ready terms of its predecessors
+    into a running ready time; the task's last row (``row_last``) places it,
+    and its earlier rows leave the core state and finish times as they are.
+    ``max`` is exact, so the result is the one a single row of all the
+    task's predecessors gives.  With ``S == T`` row ``s`` is task ``s``
+    (``row_task`` and ``row_last`` are not read), and the scan is one step
+    per task.
+
     The population is the minor axis, as in the Pallas kernel: one scan over
-    the tasks carries the finish times ``[T, P]``, and a task's predecessor
+    the rows carries the finish times ``[T, P]``, and a row's predecessor
     terms are whole rows that every candidate shares.  Nothing fetches single
     elements by a candidate's node, which XLA:TPU does one element at a time
     (about 12 ns each on a v5e): a candidate's link rates are the row
@@ -70,12 +81,12 @@ def population_makespan_ref(
     caps = at_node(jnp.broadcast_to(node_cores, (T, N)))
     take = jnp.maximum(jnp.minimum(cores[:, None], caps), 1)
     feas = jnp.any(on_node & feasible[:, :, None], axis=1)
-    valid = pred_matrix >= 0
-    psafe = jnp.where(valid, pred_matrix, 0)
-    pred_data = data[psafe]  # [T, MAXP]
+    valid = pred_rows >= 0
+    psafe = jnp.where(valid, pred_rows, 0)
+    pred_data = data[psafe]  # [S, K]
     rate_rows = dtr.T  # rate_rows[i, n] = dtr[n, i]
 
-    def place(core_free, i, ready, c, d):  # one candidate's node
+    def place(core_free, i, ready, c, d, last=None):  # one candidate's node
         row = core_free[i]
         # O(CMAX²) comparison-rank select — no sort, no gather/scatter;
         # shares the primitive (and thus bit-exact values) with the Pallas
@@ -83,28 +94,50 @@ def population_makespan_ref(
         ranks = stable_ranks(row)
         kth = kth_from_ranks(row, ranks, c)
         f = jnp.maximum(ready, kth) + d
-        return core_free.at[i].set(update_from_ranks(row, ranks, c, f)), f
+        new = update_from_ranks(row, ranks, c, f)
+        if last is not None:  # a task's earlier rows claim no core
+            new = jnp.where(last, new, row)
+        return core_free.at[i].set(new), f
 
-    def step(carry, x):
+    def fold(ready, fin, i, ps, ok, dp):  # ready after one row's predecessors
+        with jax.named_scope("preds"):
+            p_nodes = a[ps]  # [K, P]
+            rows = rate_rows[i]  # [P, N]
+            hit = p_nodes[:, :, None] == node_ids  # [K, P, N]
+            # where, not a product with a one-hot: 0 * inf would be NaN
+            rate = jnp.sum(jnp.where(hit, rows, 0.0), axis=-1)
+            transfer = jnp.where(p_nodes == i, 0.0, dp[:, None] / rate)
+            ready_terms = jnp.where(ok[:, None], fin[ps] + transfer, _NEG)
+            return jnp.maximum(ready, jnp.max(ready_terms, axis=0, initial=-1e30))
+
+    def step(carry, x):  # one row per task
         core_free, fin = carry  # [P, N, Cmax], [T, P]
-        j, i, ps, ok, dp, r, d, c = x  # i, d, c [P]; ps, ok, dp [MAXP]
-        p_nodes = a[ps]  # [MAXP, P]
-        rows = rate_rows[i]  # [P, N]
-        hit = p_nodes[:, :, None] == node_ids  # [MAXP, P, N]
-        # where, not a product with a one-hot: 0 * inf would be NaN
-        rate = jnp.sum(jnp.where(hit, rows, 0.0), axis=-1)
-        transfer = jnp.where(p_nodes == i, 0.0, dp[:, None] / rate)
-        ready_terms = jnp.where(ok[:, None], fin[ps] + transfer, _NEG)
-        ready = jnp.maximum(r, jnp.max(ready_terms, axis=0, initial=-1e30))
+        j, i, ps, ok, dp, r, d, c = x  # i, d, c [P]; ps, ok, dp [K]
+        ready = fold(r, fin, i, ps, ok, dp)
         core_free, f = jax.vmap(place)(core_free, i, ready, c, d)
         return (core_free, fin.at[j].set(f)), None
+
+    def row_step(carry, x):  # a task over one or more rows
+        core_free, fin, acc = carry  # acc [P]: ready so far over the task's rows
+        j, i, ps, ok, dp, r, d, c, last = x
+        acc = fold(acc, fin, i, ps, ok, dp)
+        place_row = functools.partial(place, last=last)
+        core_free, f = jax.vmap(place_row)(core_free, i, jnp.maximum(r, acc), c, d)
+        fin = fin.at[j].set(jnp.where(last, f, fin[j]))
+        return (core_free, fin, jnp.where(last, _NEG, acc)), None
 
     # state shaped from the inputs, so under shard_map the carry varies over
     # the same mesh axes as what the scan writes into it
     fin0 = jnp.zeros_like(dur, dtype=jnp.float32)
     core_free0 = jnp.broadcast_to(init_free, (P,) + init_free.shape)
-    xs = (jnp.arange(T), a, psafe, valid, pred_data, release, dur, take)
-    (_, fin), _ = jax.lax.scan(step, (core_free0, fin0), xs)
+    if pred_rows.shape[0] == T:
+        xs = (jnp.arange(T), a, psafe, valid, pred_data, release, dur, take)
+        (_, fin), _ = jax.lax.scan(step, (core_free0, fin0), xs)
+    else:
+        xs = (row_task, a[row_task], psafe, valid, pred_data, release[row_task],
+              dur[row_task], take[row_task], row_last)
+        acc0 = jnp.full_like(dur[0], _NEG)
+        (_, fin, _), _ = jax.lax.scan(row_step, (core_free0, fin0, acc0), xs)
     makespan = jnp.max(fin, axis=0, initial=0.0)
     violations = jnp.sum(~feas, axis=0).astype(jnp.float32)
     if deadline is not None:

@@ -115,6 +115,22 @@ def test_problem_fingerprint_sees_semantic_changes():
     assert problem_fingerprint(a) != problem_fingerprint(c)
 
 
+def test_problem_fingerprint_is_kept_and_freezes_what_it_covers(monkeypatch):
+    """The fingerprint is computed once per problem: a second call hashes
+    nothing, and an in-place change afterwards raises instead of leaving
+    the kept key stale."""
+    from repro.core import workload_model
+
+    a = build_problem(mri_system(), mri_workload())
+    key = problem_fingerprint(a)
+    monkeypatch.setattr(workload_model, "canonical_hash", None)  # any hash would fail
+    assert problem_fingerprint(a) == key
+    for array in (a.durations, a.feasible, a.dtr, a.pred_matrix, a.release):
+        assert not array.flags.writeable
+    with pytest.raises(ValueError, match="read-only"):
+        a.feasible[:, 1] = False
+
+
 def test_scenario_fingerprint_survives_json_roundtrip():
     s = Scenario(name="fp", system=mri_system(), workload=mri_workload())
     from repro.core.api import scenario_from_json

@@ -122,6 +122,71 @@ def test_sim_reproduces_oracle_timing_bit_for_bit():
     assert violations == sched.violations
 
 
+def _walk_per_task(problem, assignment, dtype, speed_factors=None, jitter_mults=None):
+    """The replay task by task in numpy arrays (sorted core rows, the
+    predecessors' terms gathered per task): the formulation that
+    ``run_schedule``'s walk over scalars has to reproduce bit for bit."""
+    durations = problem.durations
+    if speed_factors is not None:
+        durations = durations / np.maximum(speed_factors, 1e-9)[None, :]
+    durations, data, dtr = (a.astype(dtype) for a in (durations, problem.data, problem.dtr))
+    release, inf = problem.release.astype(dtype), dtype(1e30)
+    rows = [np.zeros(max(int(c), 1), dtype) for c in problem.node_cores]
+    T = problem.num_tasks
+    start, finish = np.zeros(T, dtype), np.zeros(T, dtype)
+    indptr, indices = problem.pred_csr
+    for j in range(T):
+        i = int(assignment[j])
+        ready = release[j]
+        ps = indices[indptr[j] : indptr[j + 1]]
+        if ps.size:
+            ips = assignment[ps]
+            rates = dtr[ips, i]
+            ok = np.isfinite(rates) & (rates > 0)
+            with np.errstate(divide="ignore", invalid="ignore"):
+                transfer = np.where(ips == i, dtype(0.0),
+                                    np.where(ok, data[ps] / np.where(ok, rates, 1), inf))
+            ready = np.maximum(ready, (finish[ps] + transfer).max())
+        row = rows[i]
+        c = int(max(1, min(problem.cores[j], row.size)))
+        s = np.maximum(ready, row[c - 1])
+        dur = durations[j, i] if jitter_mults is None else durations[j, i] * jitter_mults[j]
+        f = s + dur
+        rows[i] = np.sort(np.concatenate([row[c:], np.full(c, f, dtype)]))
+        start[j], finish[j] = s, f
+    violations = int(sum(not problem.feasible[j, assignment[j]] for j in range(T)))
+    return start, finish, violations
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.float32])
+@pytest.mark.parametrize("perturb", ["none", "jitter", "speed", "both"])
+def test_run_schedule_walk_matches_per_task_arrays(dtype, perturb):
+    """The scalar walk (edge transfers computed up front) gives the per-task
+    array formulation's starts, finishes and violations bit for bit, also
+    over dead links, a node without cores and infeasible placements."""
+    import dataclasses
+
+    base = _random_problem(17, 40, 6)
+    dtr = base.dtr.copy()
+    dtr[0, 1], dtr[2, 3] = np.inf, 0.0  # dead links
+    node_cores = base.node_cores.copy()
+    node_cores[4] = 0
+    problem = dataclasses.replace(base, dtr=dtr, node_cores=node_cores)
+    rng = np.random.default_rng(17)
+    A = rng.integers(0, problem.num_nodes, problem.num_tasks)
+    kw = {}
+    if perturb in ("speed", "both"):
+        kw["speed_factors"] = rng.uniform(0.5, 2.0, problem.num_nodes)
+    if perturb in ("jitter", "both"):
+        kw["jitter_mults"] = rng.lognormal(0.0, 0.3, problem.num_tasks)
+    got = run_schedule(problem, A, dtype=dtype, **kw)
+    want = _walk_per_task(problem, A, dtype, **kw)
+    for g, w in zip(got[:2], want[:2]):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(g, w)
+    assert got[2] == want[2] > 0
+
+
 def test_truth_execution_matches_oracle_exactly():
     """The service's truth executor replays through engine.sim — with no
     perturbation its finish times are the oracle's, bit for bit."""
@@ -224,7 +289,7 @@ def test_pack_is_read_only_and_padding_is_neutral():
 def test_pack_rejects_too_small_bucket():
     problem = _random_problem(19, 12, 3)
     with pytest.raises(ValueError, match="exceeds bucket"):
-        pack(problem, (4, 4, 4, 1))
+        pack(problem, (4, 4, 4, 1, 4))
 
 
 def test_pack_cache_is_byte_bounded():
@@ -386,16 +451,18 @@ def test_engine_selection_never_leaks_into_exact_solvers():
 # -----------------------------------------------------------------------------
 
 
-def _sweep_program_inputs():
+def _sweep_program_inputs(family: str = "layered"):
     import jax
 
+    from repro.core import montage_workflow
     from repro.engine import stack_packed
 
     system = synthetic_system(3, seed=3)
-    problems = [
-        build_problem(system, Workload((random_layered_workflow(10, seed=100 + i, max_cores=4),)))
-        for i in range(2)
-    ]
+    if family == "montage":  # joins of 110 and 89 fits: several rows a join
+        workflows = [montage_workflow(6, 6, seed=1), montage_workflow(5, 6, seed=2)]
+    else:
+        workflows = [random_layered_workflow(10, seed=100 + i, max_cores=4) for i in range(2)]
+    problems = [build_problem(system, Workload((wf,))) for wf in workflows]
     arrays, bucket = stack_packed(problems)
     logits = np.zeros((2, bucket[0], bucket[1]), np.float32)
     keys = np.asarray(jax.random.split(jax.random.PRNGKey(0), 2))
@@ -418,6 +485,24 @@ def test_ga_sweep_program_carries_fitness_scope():
     run = _ga_sweep_core("fixed", 8, 3, 4, 2)
     text = run.lower(*_sweep_program_inputs()).compile().as_text()
     assert _fitness_fusions(text)
+
+
+@pytest.mark.parametrize("family", ["layered", "montage"])
+def test_preds_scope_sits_inside_fitness(family):
+    """A row's predecessor terms run under ``preds`` inside ``fitness``, with
+    one row per task and with joins over several rows."""
+    import re
+
+    from repro.core.metaheuristics import _ga_sweep_core
+
+    run = _ga_sweep_core("fixed", 8, 2, 4, 2)
+    text = run.lower(*_sweep_program_inputs(family)).compile().as_text()
+    # full paths only: a reduction's own region carries a relative name
+    paths = [p.split("/") for p in re.findall(r'op_name="(jit\([^"]*)"', text)]
+    preds = [p for p in paths if "preds" in p]
+    assert preds
+    for p in preds:
+        assert any(re.fullmatch(r"(\w+\()*fitness\)*", seg) for seg in p[: p.index("preds")])
 
 
 def test_fitness_scope_is_metadata_only(monkeypatch):

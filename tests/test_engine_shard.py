@@ -55,6 +55,16 @@ def _family(n, tasks=10, nodes=3, seed0=100):
     ]
 
 
+def _mosaics(n, nodes=3):
+    """Montage mosaics of 5x6 and 6x6 images: joins of 89 to 110 fits take
+    several predecessor rows, and the instances need different row counts."""
+    from repro.core import montage_workflow
+
+    system = synthetic_system(nodes, seed=nodes)
+    return [build_problem(system, Workload((montage_workflow(5 + i % 2, 6, seed=i),)))
+            for i in range(n)]
+
+
 # -----------------------------------------------------------------------------
 # shard-count / padding math (device-count passed explicitly — no jax needed)
 # -----------------------------------------------------------------------------
@@ -194,7 +204,8 @@ def _check_sweep_span_tree(spans, instances: int, shards: int) -> None:
     (root,) = [s for s in spans if s[1] is None]
     assert root[2] == "mh.ga_sweep"
     assert root[5]["instances"] == instances and root[5]["shards"] == shards
-    assert "bucket" in root[5]
+    bucket = [int(d) for d in root[5]["bucket"].split("x")]
+    assert (root[5]["tasks"], root[5]["rows"]) == (bucket[0], bucket[4])
     children = [s for s in spans if s[1] == root[0]]
     assert [s[2] for s in children] == (
         ["mh.ga_sweep.prepare", "mh.ga_sweep.device"] + ["mh.finish"] * instances)
@@ -223,8 +234,54 @@ def _traced_sweep_spans(problems, **kw):
             for s in obs.TRACER.spans]
 
 
+def test_sweep_logits_on_device_match_the_host_mask():
+    """The sweep's sampling logits, made on the device from the stacked
+    feasibility, equal the host mask they replace: 0 on feasible nodes, node
+    0 for a task with none and for padded tasks, ``_NEG`` elsewhere."""
+    from repro.core.metaheuristics import _NEG, _sampling_logits
+    from repro.engine import stack_packed
+
+    problems = _family(2, tasks=10) + _family(1, tasks=7, nodes=2, seed0=7)
+    problems[1].feasible[3] = False  # a task no node can run
+    arrays, (Tb, Nb, *_) = stack_packed(problems)
+    want = np.full((3, Tb, Nb), _NEG, np.float32)
+    for b, p in enumerate(problems):
+        safe = p.feasible.copy()
+        safe[~safe.any(axis=1), 0] = True
+        want[b, : p.num_tasks, : p.num_nodes][safe] = 0.0
+        want[b, p.num_tasks :, 0] = 0.0
+    got = np.asarray(_sampling_logits()(arrays["feasible"]))
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got, want)
+
+
+def test_ga_sweep_copies_each_instance_once():
+    """A family that meets again is stacked on the device, and its logits
+    are made there: the traced second call copies only its PRNG keys."""
+    spans = _traced_sweep_spans(_family(3))
+    (prepare,) = [s for s in spans if s[2] == "mh.ga_sweep.prepare"]
+    assert prepare[5]["h2d_bytes"] == 3 * 2 * 4
+
+
 def test_ga_sweep_span_tree_one_device():
     _check_sweep_span_tree(_traced_sweep_spans(_family(3)), instances=3, shards=1)
+
+
+def test_ga_sweep_reports_predecessor_rows():
+    """The call's span and the ``engine.pred_rows`` gauge give the bucket's
+    row count ``S`` beside its tasks ``T``: one row per task for a layered
+    family, more where Montage joins span several rows."""
+    from repro import obs
+    from repro.engine import common_bucket
+
+    for problems in (_family(2), _mosaics(3)):
+        spans = _traced_sweep_spans(problems)
+        _check_sweep_span_tree(spans, instances=len(problems), shards=1)
+        T, _, _, _, S = common_bucket(problems)
+        root = next(s for s in spans if s[1] is None)
+        assert (root[5]["tasks"], root[5]["rows"]) == (T, S)
+        assert obs.METRICS.snapshot()["gauges"]["engine.pred_rows"] == S
+    assert S > T
 
 
 _SPAN_TREE_SCRIPT = textwrap.dedent(
@@ -261,6 +318,8 @@ def test_ga_sweep_span_tree_four_devices_subprocess():
 
 _MULTI_DEVICE_SCRIPT = textwrap.dedent(
     """
+    import sys
+
     import numpy as np
 
     from repro.core import ObjectiveWeights, Workload, build_problem, synthetic_system
@@ -322,6 +381,18 @@ _MULTI_DEVICE_SCRIPT = textwrap.dedent(
     # per-generation histories)
     on = ga_sweep(problems, pop_size=8, generations=3, seed=0)
     off = ga_sweep(problems, pop_size=8, generations=3, seed=0, shard="off")
+    for ra, rb in zip(on, off):
+        assert np.array_equal(ra.schedule.assignment, rb.schedule.assignment)
+        assert np.array_equal(ra.history, rb.history)
+
+    # --- Montage joins over several predecessor rows, instances of unequal
+    # row counts: the sharded sweep is the one-device sweep bit for bit
+    sys.path.insert(0, "tests")
+    from test_engine_shard import _mosaics
+
+    mosaics = _mosaics(4)
+    on = ga_sweep(mosaics, pop_size=8, generations=2, seed=0)
+    off = ga_sweep(mosaics, pop_size=8, generations=2, seed=0, shard="off")
     for ra, rb in zip(on, off):
         assert np.array_equal(ra.schedule.assignment, rb.schedule.assignment)
         assert np.array_equal(ra.history, rb.history)

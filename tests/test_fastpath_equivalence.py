@@ -107,7 +107,7 @@ def test_jnp_pallas_numpy_bit_for_bit(name, problem):
         mk_k, viol_k = population_makespan_pallas(
             jnp.asarray(A_pad, jnp.int32),
             jp["durations"], jp["cores"], jp["data"], jp["feasible"],
-            jp["release"], jp["pred_matrix"], jp["dtr"], jp["init_free"],
+            jp["release"], jp["pred_rows"], jp["dtr"], jp["init_free"],
             tile=4, stream=stream,
         )
         np.testing.assert_array_equal(np.asarray(mk_k), mk_jnp)

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from repro.core import Workload, build_problem, evaluate_assignment, mri_system, mri_workload, random_layered_workflow, synthetic_system
 from repro.engine import pack
+from repro.engine.packed import task_rows
 from repro.kernels import ops
 from repro.kernels.makespan import population_makespan_pallas
 from repro.kernels.ref import population_makespan_ref
@@ -37,11 +38,11 @@ def test_kernel_matches_oracles(num_tasks, num_nodes, seed, pop):
     mk_ref, v_ref = population_makespan_ref(
         A, durations=jp["durations"], cores=jp["cores"], data=jp["data"],
         feasible=jp["feasible"], release=jp["release"],
-        pred_matrix=jp["pred_matrix"], dtr=jp["dtr"], init_free=jp["init_free"],
+        pred_rows=jp["pred_rows"], dtr=jp["dtr"], init_free=jp["init_free"],
     )
     mk_k, v_k = population_makespan_pallas(
         A, jp["durations"], jp["cores"], jp["data"], jp["feasible"],
-        jp["release"], jp["pred_matrix"], jp["dtr"], jp["init_free"], tile=8,
+        jp["release"], jp["pred_rows"], jp["dtr"], jp["init_free"], tile=8,
     )
     np.testing.assert_allclose(np.asarray(mk_k), np.asarray(mk_ref), rtol=1e-4)
     np.testing.assert_array_equal(np.asarray(v_k), np.asarray(v_ref))
@@ -60,7 +61,7 @@ def test_ops_dispatch_pads_population():
         mk, v = ops.population_makespan(
             A, durations=jp["durations"], cores=jp["cores"], data=jp["data"],
             feasible=jp["feasible"], release=jp["release"],
-            pred_matrix=jp["pred_matrix"], dtr=jp["dtr"], init_free=jp["init_free"],
+            pred_rows=jp["pred_rows"], dtr=jp["dtr"], init_free=jp["init_free"],
         )
     finally:
         ops.configure(use_pallas=False)
@@ -68,7 +69,7 @@ def test_ops_dispatch_pads_population():
     mk_ref, _ = population_makespan_ref(
         A, durations=jp["durations"], cores=jp["cores"], data=jp["data"],
         feasible=jp["feasible"], release=jp["release"],
-        pred_matrix=jp["pred_matrix"], dtr=jp["dtr"], init_free=jp["init_free"],
+        pred_rows=jp["pred_rows"], dtr=jp["dtr"], init_free=jp["init_free"],
     )
     np.testing.assert_allclose(np.asarray(mk), np.asarray(mk_ref), rtol=1e-4)
 
@@ -93,7 +94,7 @@ def test_ga_with_pallas_backend_matches_jnp():
 # interpreter, bit for bit, on the cases its predecessor reads must get right
 # -----------------------------------------------------------------------------
 
-_EVAL_KEYS = ("durations", "cores", "data", "feasible", "release", "pred_matrix",
+_EVAL_KEYS = ("durations", "cores", "data", "feasible", "release", "pred_rows",
               "dtr", "init_free", "node_cores")
 
 
@@ -113,7 +114,8 @@ def _case(name):
         # the bucket's MAXP (16) is well above the real in-degree
         prob = _layered(20, 5, 11)
         assert int((prob.pred_matrix >= 0).sum(axis=1).max()) < 16
-        packs = [pack(prob, pack(prob).bucket[:3] + (16,), use_cache=False)]
+        tb = pack(prob).bucket[0]
+        packs = [pack(prob, pack(prob).bucket[:3] + (16, tb), use_cache=False)]
         pops = [rng.integers(0, prob.num_nodes, (8, prob.num_tasks))]
         probs = [prob]
     elif name == "colocated":
@@ -217,7 +219,8 @@ def test_task_step_reads_whole_rows_at_table9_bucket(batch):
     T, N, C, M, P = 512, 512, 64, 64, 64
     shapes = {"durations": ((T, N), jnp.float32), "cores": ((T,), jnp.int32),
               "data": ((T,), jnp.float32), "feasible": ((T, N), jnp.bool_),
-              "release": ((T,), jnp.float32), "pred_matrix": ((T, M), jnp.int32),
+              "release": ((T,), jnp.float32), "pred_rows": ((T, M), jnp.int32),
+              "row_task": ((T,), jnp.int32), "row_last": ((T,), jnp.bool_),
               "dtr": ((N, N), jnp.float32), "init_free": ((N, C), jnp.float32),
               "node_cores": ((N,), jnp.int32), "usage_fixed": ((T,), jnp.float32),
               "usage_weighted": ((T, N), jnp.float32), "deadline": ((T,), jnp.float32),
@@ -242,3 +245,119 @@ def test_task_step_reads_whole_rows_at_table9_bucket(batch):
         operand = eqn.invars[0].aval.shape
         sizes = eqn.params["slice_sizes"]
         assert sizes[-1] == operand[-1] > 1, (operand, sizes)
+
+
+# -----------------------------------------------------------------------------
+# predecessor rows: a join spread over several rows scores bit for bit like
+# one dense row of all its predecessors
+# -----------------------------------------------------------------------------
+
+_TASK_KEYS = tuple(k for k in _EVAL_KEYS if k != "pred_rows")
+
+
+def _montage(rows, cols, nodes, seed):
+    from repro.core import montage_workflow
+
+    system = synthetic_system(nodes, seed=seed)
+    return build_problem(system, Workload((montage_workflow(rows, cols, seed=seed),)))
+
+
+def _dense_makespans(prob, pop):
+    """The evaluator with one dense row of every task's predecessors, as the
+    engine packed them before predecessor rows: ``[T, MAXP]``, no
+    ``row_task``."""
+    dense = pack(prob, use_cache=False)
+    assert dense.bucket[4] == dense.bucket[0]  # every task fits one row
+    arr = dense.device_arrays()
+    mk, viol = population_makespan_ref(
+        jnp.asarray(_padded(pop, dense.bucket[0])), **{k: arr[k] for k in _TASK_KEYS},
+        pred_rows=arr["pred_rows"])
+    return np.asarray(mk), np.asarray(viol)
+
+
+def _row_case(name):
+    """``(problems, populations, buckets)``: Montage mosaics whose joins take
+    several rows at a forced row width ``K``, alone or mixed under vmap."""
+    from repro.engine import common_bucket
+
+    grids = {"3x3": [(3, 3, 6, 31)], "4x5": [(4, 5, 8, 32)],
+             "mixed": [(3, 3, 6, 33), (4, 5, 8, 34), (2, 5, 5, 35)]}[name.split("_k")[0]]
+    probs = [_montage(*g) for g in grids]
+    width = int(name.split("_k")[1])
+    t, n, c, _, _ = common_bucket(probs)
+    rows = max(int(task_rows(p, width).sum()) + t - p.num_tasks for p in probs)
+    rng = np.random.default_rng(sum(map(ord, name)))
+    pops = [rng.integers(0, p.num_nodes, (8, p.num_tasks)) for p in probs]
+    return probs, pops, (t, n, c, width, rows + 5)  # 5 filler rows
+
+
+@pytest.mark.parametrize("name", ["3x3_k2", "3x3_k4", "4x5_k2", "4x5_k4", "mixed_k4"])
+def test_predecessor_rows_bit_for_bit(name):
+    """At a forced row width the joins (in-degree 20 and 9 on a 3x3 mosaic,
+    55 and 20 on 4x5) span several rows, and instances under vmap need
+    different row counts: every makespan is the dense evaluator's and the
+    f32 oracle's bit for bit, and within float tolerance of the f64 oracle;
+    the Pallas dispatcher refuses the rows and falls back to the same
+    evaluator."""
+    import jax
+
+    from repro import obs
+    from repro.engine.backends import population_fitness_from_arrays
+
+    probs, pops, bucket = _row_case(name)
+    packs = [pack(p, bucket, use_cache=False) for p in probs]
+    assert all(int(pk.row_last.sum()) == bucket[0] for pk in packs)
+    assert any(int(task_rows(p, bucket[3]).max()) > 1 for p in probs)
+    arrays = {k: jnp.stack([pk.device_arrays()[k] for pk in packs])
+              for k in packs[0].device_arrays()}
+    A = jnp.stack([jnp.asarray(_padded(p, bucket[0])) for p in pops])
+
+    def fitness(pop, arr):
+        return population_fitness_from_arrays(pop, arr, 0.0, 1.0, "fixed")
+
+    _, mk = jax.jit(jax.vmap(fitness))(A, arrays)
+    mk = np.asarray(mk)
+    for b, (prob, pop, pk) in enumerate(zip(probs, pops, packs)):
+        dense_mk, dense_viol = _dense_makespans(prob, pop)
+        np.testing.assert_array_equal(mk[b], dense_mk)
+        for k in range(pop.shape[0]):
+            s32 = evaluate_assignment(prob, pop[k], dtype=np.float32)
+            assert np.float32(s32.makespan) == mk[b, k]
+            s64 = evaluate_assignment(prob, pop[k])
+            assert s64.makespan == pytest.approx(float(mk[b, k]), rel=1e-4, abs=1e-4)
+        arr = pk.device_arrays()
+        before = obs.METRICS.snapshot()["counters"].get("engine.traced.ref", 0)
+        mk_p, viol_p = ops.population_makespan(
+            A[b], **{k: arr[k] for k in _TASK_KEYS if k != "node_cores"},
+            pred_rows=arr["pred_rows"], row_task=arr["row_task"], row_last=arr["row_last"],
+            force=True)
+        assert obs.METRICS.snapshot()["counters"]["engine.traced.ref"] == before + 1
+        np.testing.assert_array_equal(np.asarray(mk_p), dense_mk)
+        np.testing.assert_array_equal(np.asarray(viol_p), dense_viol)
+
+
+def test_one_row_per_task_packs_the_dense_matrix():
+    """Where every in-degree fits one row (K = the bucket's MAXP, S = T, as
+    every Table IX instance), the rows are the dense ``[T, MAXP]`` matrix
+    and the scan is the one-step-per-task program, whatever ``row_task``
+    and ``row_last`` hold."""
+    import jax
+
+    prob = _layered(60, 12, 36)
+    pk = pack(prob, use_cache=False)
+    T, _, _, K, S = pk.bucket
+    assert S == T and K >= prob.pred_matrix.shape[1] > 1
+    dense = np.full((T, K), -1, np.int32)
+    dense[: prob.num_tasks, : prob.pred_matrix.shape[1]] = prob.pred_matrix
+    np.testing.assert_array_equal(pk.pred_rows, dense)
+    np.testing.assert_array_equal(pk.row_task, np.arange(T))
+    assert pk.row_last.all()
+    arr = pk.device_arrays()
+    A = jnp.asarray(_padded(np.zeros((4, prob.num_tasks), np.int64), T))
+    kw = {k: arr[k] for k in _TASK_KEYS} | {"pred_rows": arr["pred_rows"]}
+
+    def scans(**rows):
+        jaxpr = jax.make_jaxpr(lambda a: population_makespan_ref(a, **kw, **rows))(A).jaxpr
+        return [str(e) for e in _eqns(jaxpr) if e.primitive.name == "scan"]
+
+    assert scans() == scans(row_task=arr["row_task"], row_last=arr["row_last"])

@@ -27,7 +27,7 @@ from repro.kernels import makespan
 
 #: a small problem packed into a bucket with distinct dims, so each array's
 #: dims can be told apart and rescaled to the bucket under test
-_PROBE_BUCKET = (16, 4, 8, 2)  # (T, N, CMAX, MAXP)
+_PROBE_BUCKET = (16, 4, 8, 2, 32)  # (T, N, CMAX, K, S)
 
 
 @pytest.fixture(scope="module")
@@ -48,7 +48,7 @@ def one_chip(topo):
 
 def _fitness_arrays(bucket, one_chip, batch=None):
     """Shape structs of the packed fitness arrays at ``bucket`` (T, N,
-    CMAX, MAXP), with an optional leading instance axis."""
+    CMAX, K, S), with an optional leading instance axis."""
     problem = build_problem(
         synthetic_system(3, seed=1),
         Workload((random_layered_workflow(6, seed=1, max_cores=4),)),
@@ -94,7 +94,7 @@ def test_makespan_kernel_compiles_at_table9_size(one_chip, stream):
 def test_jax_population_core_compiles_at_table9_bucket(one_chip):
     """The jax engine's fitness core (the jnp evaluator) at the 500x500
     bucket with a 64-candidate population."""
-    arrays = _fitness_arrays((512, 512, 64, 64), one_chip)
+    arrays = _fitness_arrays((512, 512, 64, 64, 512), one_chip)
     pop = jax.ShapeDtypeStruct((64, 512), jnp.int32, sharding=one_chip)
     core = _population_core("fixed")
     compiled = core.lower(pop, arrays, 1.0, 1.0).compile()
@@ -105,7 +105,7 @@ def test_jax_population_core_compiles_at_table9_bucket(one_chip):
 def test_ga_sweep_core_compiles_at_service_bucket(one_chip):
     """The batched ``ga_sweep`` program an admission group runs on the
     1008-node ``large`` topology: 4 instances of up to 16 tasks."""
-    B, bucket = 4, (16, 1024, 64, 4)
+    B, bucket = 4, (16, 1024, 64, 4, 16)
     arrays = _fitness_arrays(bucket, one_chip, batch=B)
     logits = jax.ShapeDtypeStruct((B, bucket[0], bucket[1]), jnp.float32, sharding=one_chip)
     keys = jax.ShapeDtypeStruct((B, 2), jnp.uint32, sharding=one_chip)
@@ -121,11 +121,27 @@ def test_ga_sweep_core_compiles_at_table9_bucket(one_chip):
     """The batched ``ga_sweep`` program of the Table IX benchmark cell: 8
     instances at the 500x500 bucket, 64 candidates, 20 generations, the
     evaluator's task step reading whole rows population-minor."""
-    B, bucket = 8, (512, 512, 64, 64)
+    B, bucket = 8, (512, 512, 64, 64, 512)
     arrays = _fitness_arrays(bucket, one_chip, batch=B)
     logits = jax.ShapeDtypeStruct((B, bucket[0], bucket[1]), jnp.float32, sharding=one_chip)
     keys = jax.ShapeDtypeStruct((B, 2), jnp.uint32, sharding=one_chip)
     run = _ga_sweep_core("fixed", 64, 20, 4, 2)
     compiled = run.lower(arrays, logits, keys, 1.0, 1.0, 0.08).compile()
     assert re.search(r'fusion[.\w]* = .*op_name="[^"]*/fitness/', compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30  # fits one v5e
+
+
+def test_ga_sweep_core_compiles_at_montage_bucket(one_chip):
+    """The batched ``ga_sweep`` program of the Montage benchmark cell: 8
+    mosaics of 1,019 tasks at the 1024/512/64 bucket, predecessors in 1088
+    rows of 16 (the joins of 649, 182 and 183 take 41, 12 and 12 rows), 64
+    candidates, 20 generations."""
+    B, bucket = 8, (1024, 512, 64, 16, 1088)
+    arrays = _fitness_arrays(bucket, one_chip, batch=B)
+    assert arrays["pred_rows"].shape == (B, 1088, 16)
+    logits = jax.ShapeDtypeStruct((B, bucket[0], bucket[1]), jnp.float32, sharding=one_chip)
+    keys = jax.ShapeDtypeStruct((B, 2), jnp.uint32, sharding=one_chip)
+    run = _ga_sweep_core("fixed", 64, 20, 4, 2)
+    compiled = run.lower(arrays, logits, keys, 1.0, 1.0, 0.08).compile()
+    assert re.search(r'op_name="[^"]*/fitness/[^"]*/preds/', compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30  # fits one v5e
