@@ -8,14 +8,17 @@ Pallas lowering (the CPU dry-run lowers these).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+from repro.kernels import rowdma
 from repro.kernels.select import kth_from_ranks, stable_ranks, update_from_ranks
 
 _NEG = -1e30
+#: what a stored core-state row holds beyond its node's ``CMAX`` cores
+#: (never read)
+_PAD = 1e30
 
 
 # -----------------------------------------------------------------------------
@@ -63,7 +66,15 @@ def population_makespan_ref(
     ``dtr.T[i]``, selected at the predecessors' nodes by a masked sum over
     the node axis, and its duration, core count and feasibility are selected
     the same way before the scan.  A masked sum of one value and zeros is
-    that value, so every number is the one an indexed read gives."""
+    that value, so every number is the one an indexed read gives.
+
+    The core state ``[P, N, W]`` stores each row ``W`` lanes wide
+    (:func:`repro.kernels.rowdma.row_width`); a step reads the first
+    ``CMAX`` and writes every candidate's new row back through
+    :func:`repro.kernels.rowdma.write_rows`: async row copies on a TPU,
+    which skip a row whose ``row_last`` is false, XLA's scatter elsewhere.
+    The trace-time counter ``engine.traced.row_dma`` counts evaluators
+    traced with that write."""
     T, N = durations.shape
     if node_cores is None:
         # padding entries are "never free" (+1e30); real cores start ≤ horizon
@@ -86,8 +97,12 @@ def population_makespan_ref(
     pred_data = data[psafe]  # [S, K]
     rate_rows = dtr.T  # rate_rows[i, n] = dtr[n, i]
 
-    def place(core_free, i, ready, c, d, last=None):  # one candidate's node
-        row = core_free[i]
+    cmax = init_free.shape[1]
+    width = rowdma.row_width(cmax)  # stored row width: whole lane tiles
+
+    def place(core_free, i, ready, c, d):  # one candidate's node
+        stored = core_free[i]
+        row = stored[:cmax]
         # O(CMAX²) comparison-rank select — no sort, no gather/scatter;
         # shares the primitive (and thus bit-exact values) with the Pallas
         # kernel.
@@ -95,9 +110,12 @@ def population_makespan_ref(
         kth = kth_from_ranks(row, ranks, c)
         f = jnp.maximum(ready, kth) + d
         new = update_from_ranks(row, ranks, c, f)
-        if last is not None:  # a task's earlier rows claim no core
-            new = jnp.where(last, new, row)
-        return core_free.at[i].set(new), f
+        return jnp.pad(new, (0, width - cmax), constant_values=_PAD), stored, f
+
+    def write(core_free, i, ready, c, d, last=None):
+        # a task's earlier rows (``last`` false) claim no core
+        new, stored, f = jax.vmap(place)(core_free, i, ready, c, d)
+        return rowdma.write_rows(core_free, new, i, last, stored), f
 
     def fold(ready, fin, i, ps, ok, dp):  # ready after one row's predecessors
         with jax.named_scope("preds"):
@@ -114,22 +132,23 @@ def population_makespan_ref(
         core_free, fin = carry  # [P, N, Cmax], [T, P]
         j, i, ps, ok, dp, r, d, c = x  # i, d, c [P]; ps, ok, dp [K]
         ready = fold(r, fin, i, ps, ok, dp)
-        core_free, f = jax.vmap(place)(core_free, i, ready, c, d)
+        core_free, f = write(core_free, i, ready, c, d)
         return (core_free, fin.at[j].set(f)), None
 
     def row_step(carry, x):  # a task over one or more rows
         core_free, fin, acc = carry  # acc [P]: ready so far over the task's rows
         j, i, ps, ok, dp, r, d, c, last = x
         acc = fold(acc, fin, i, ps, ok, dp)
-        place_row = functools.partial(place, last=last)
-        core_free, f = jax.vmap(place_row)(core_free, i, jnp.maximum(r, acc), c, d)
+        core_free, f = write(core_free, i, jnp.maximum(r, acc), c, d, last)
         fin = fin.at[j].set(jnp.where(last, f, fin[j]))
         return (core_free, fin, jnp.where(last, _NEG, acc)), None
 
     # state shaped from the inputs, so under shard_map the carry varies over
     # the same mesh axes as what the scan writes into it
     fin0 = jnp.zeros_like(dur, dtype=jnp.float32)
-    core_free0 = jnp.broadcast_to(init_free, (P,) + init_free.shape)
+    stored0 = jnp.pad(init_free, ((0, 0), (0, width - cmax)), constant_values=_PAD)
+    core_free0 = jnp.broadcast_to(stored0, (P,) + stored0.shape)
+    obs.METRICS.counter("engine.traced.row_dma").inc()  # trace-time count
     if pred_rows.shape[0] == T:
         xs = (jnp.arange(T), a, psafe, valid, pred_data, release, dur, take)
         (_, fin), _ = jax.lax.scan(step, (core_free0, fin0), xs)

@@ -196,9 +196,12 @@ def test_population_minor_evaluator_bit_for_bit(name):
 
 
 def _eqns(jaxpr):
-    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it, short of
+    a Pallas kernel's body (its loops and reads are Mosaic's, not XLA's)."""
     for eqn in jaxpr.eqns:
         yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
         for param in eqn.params.values():
             for p in param if isinstance(param, (tuple, list)) else (param,):
                 inner = getattr(p, "jaxpr", p)
@@ -361,3 +364,143 @@ def test_one_row_per_task_packs_the_dense_matrix():
         return [str(e) for e in _eqns(jaxpr) if e.primitive.name == "scan"]
 
     assert scans() == scans(row_task=arr["row_task"], row_last=arr["row_last"])
+
+
+# -----------------------------------------------------------------------------
+# the core-state row write: the TPU's row-DMA kernel, run in the Pallas TPU
+# interpreter, against the scatter every other platform keeps
+# -----------------------------------------------------------------------------
+
+
+def _row_write_case(cmax, lead, seed):
+    """``(state, rows, idx)``: a core state of 6 nodes and 8 candidates whose
+    rows are stored ``row_width(cmax)`` wide, padding as the evaluator's."""
+    from repro.kernels import ref, rowdma
+
+    rng = np.random.default_rng(seed)
+    P, N, W = 8, 6, rowdma.row_width(cmax)
+
+    def stored(shape):
+        x = np.full(shape + (W,), ref._PAD, np.float32)
+        x[..., :cmax] = rng.normal(size=shape + (cmax,))
+        return jnp.asarray(x)
+
+    idx = jnp.asarray(rng.integers(0, N, lead + (P,)), jnp.int32)
+    return stored(lead + (P, N)), stored(lead + (P,)), idx
+
+
+def _scattered(state, rows, idx, last):
+    import jax
+
+    put = jax.vmap(lambda s, i, r: s.at[i].set(r))(state, idx, rows)
+    return put if last else state
+
+
+@pytest.mark.parametrize("cmax,width", [(64, 128), (256, 256)])
+@pytest.mark.parametrize("last", [True, False], ids=["last", "not_last"])
+def test_row_dma_writes_the_rows_scatter_writes(cmax, width, last):
+    """One instance: the kernel sets row ``idx[p]`` of candidate ``p`` to
+    ``rows[p]``, bit for bit as ``.at[i].set``, and a row that is not the
+    task's last leaves the state untouched; so does XLA's path, which
+    writes the rows as read back."""
+    from repro.kernels import rowdma
+
+    assert rowdma.row_width(cmax) == width
+    state, rows, idx = _row_write_case(cmax, (), cmax + last)
+    want = np.asarray(_scattered(state, rows, idx, last))
+    got = rowdma.row_dma(state, rows, idx, jnp.bool_(last), interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    old = state[jnp.arange(idx.shape[0]), idx]
+    np.testing.assert_array_equal(
+        np.asarray(rowdma.write_rows(state, rows, idx, jnp.bool_(last), old)), want)
+
+
+@pytest.mark.parametrize("cmax", [64, 256])
+@pytest.mark.parametrize("lead", [(3,), (2, 3)], ids=["vmap", "vmap_vmap"])
+def test_row_dma_under_vmap_is_one_kernel(cmax, lead):
+    """Under vmap (and vmap of vmap) over instances, the ``custom_vmap``
+    rule hands every instance's rows to one kernel over ``[B, P, N, W]``,
+    and each instance's ``last`` decides for its rows alone."""
+    import functools
+
+    import jax
+
+    from repro.kernels import rowdma
+
+    state, rows, idx = _row_write_case(cmax, lead, cmax + len(lead))
+    last = jnp.asarray(np.arange(np.prod(lead)).reshape(lead) % 3 != 1)
+    write = functools.partial(rowdma.row_dma, interpret=True)
+    for _ in lead:
+        write = jax.vmap(write)
+    got = np.asarray(write(state, rows, idx, last))
+    flat = [x.reshape((-1,) + x.shape[len(lead):]) for x in (state, rows, idx)]
+    for b, flag in enumerate(np.asarray(last).reshape(-1)):
+        want = _scattered(flat[0][b], flat[1][b], flat[2][b], flag)
+        np.testing.assert_array_equal(got.reshape(flat[0].shape)[b], np.asarray(want))
+    calls = [e for e in _eqns(jax.make_jaxpr(write)(state, rows, idx, last).jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert calls[0].outvars[0].aval.shape == (int(np.prod(lead)),) + state.shape[len(lead):]
+
+
+@pytest.mark.parametrize("name", ["probe", "batched", "mixed_k4"])
+def test_evaluator_scores_equal_with_either_row_write(name, monkeypatch):
+    """``population_makespan_ref`` scores every candidate the same, bit for
+    bit, whether its rows go back through XLA's scatter (this platform's
+    path) or through the TPU's row-DMA kernel: at the compile tests' probe
+    bucket, three Table IX-like instances under vmap (``S == T``), and
+    Montage mosaics whose joins take several rows (``S > T``; a join's
+    earlier rows write nothing)."""
+    import functools
+
+    import jax
+
+    from repro import obs
+    from repro.kernels import rowdma
+
+    if name == "probe":
+        prob = build_problem(
+            synthetic_system(3, seed=1),
+            Workload((random_layered_workflow(6, seed=1, max_cores=4),)))
+        probs, packs = [prob], [pack(prob, (16, 4, 8, 2, 32), use_cache=False)]
+        pops = [np.random.default_rng(5).integers(0, 3, (8, prob.num_tasks))]
+    elif name == "batched":
+        probs, pops, packs = _case(name)
+    else:
+        probs, pops, bucket = _row_case(name)
+        packs = [pack(p, bucket, use_cache=False) for p in probs]
+    T, S = packs[0].bucket[0], packs[0].bucket[4]
+    assert (S > T) == (name != "batched")
+    keys = _TASK_KEYS + ("pred_rows", "row_task", "row_last")
+    arrays = {k: jnp.stack([pk.device_arrays()[k] for pk in packs]) for k in keys}
+    A = jnp.stack([jnp.asarray(_padded(p, T)) for p in pops])
+
+    def scores():
+        return jax.vmap(lambda a, arr: population_makespan_ref(a, **arr))(A, arrays)
+
+    mk, viol = scores()
+    before = obs.METRICS.snapshot()["counters"].get("engine.traced.row_dma", 0)
+    monkeypatch.setattr(rowdma, "write_rows", functools.partial(rowdma._dma, interpret=True))
+    mk_dma, viol_dma = scores()
+    assert obs.METRICS.snapshot()["counters"]["engine.traced.row_dma"] == before + 1
+    np.testing.assert_array_equal(np.asarray(mk_dma), np.asarray(mk))
+    np.testing.assert_array_equal(np.asarray(viol_dma), np.asarray(viol))
+    for b, (prob, pop) in enumerate(zip(probs, pops)):
+        for k in range(pop.shape[0]):
+            assert np.float32(evaluate_assignment(prob, pop[k], dtype=np.float32).makespan) == mk[b, k]
+
+
+def test_row_write_lowers_to_a_scatter_off_the_tpu():
+    """Off the TPU the evaluator's row write lowers to XLA's scatter, the
+    program it had before the kernel: no Mosaic call reaches the CPU."""
+    import jax
+
+    prob = build_problem(
+        synthetic_system(3, seed=1),
+        Workload((random_layered_workflow(6, seed=1, max_cores=4),)))
+    arr = pack(prob, (16, 4, 8, 2, 32), use_cache=False).device_arrays()
+    kw = {k: arr[k] for k in _TASK_KEYS + ("pred_rows", "row_task", "row_last")}
+    pop = jnp.asarray(_padded(np.zeros((8, prob.num_tasks), np.int32), 16))
+    text = jax.jit(population_makespan_ref).lower(pop, **kw).as_text()
+    assert "scatter" in text
+    assert "tpu_custom_call" not in text and "row_dma" not in text
