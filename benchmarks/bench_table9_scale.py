@@ -3,7 +3,7 @@
 
 The paper's serial-Python numbers: MILP solves only 5×5 (0.02 s); MH needs
 77.8 s at 50×50 and 6513 s at 500×500; H reaches 5000×5000 in 560 s.  Our
-adaptation vectorizes MH fitness in JAX (DESIGN.md §2) — the side-by-side
+adaptation vectorizes MH fitness in JAX — the side-by-side
 is the §Perf "beyond-paper" evidence.  Default sizes cap at 500×500 to keep
 `-m benchmarks.run` bounded; pass --full for the 5000×5000 heuristic row.
 """

@@ -1,5 +1,5 @@
-"""Benchmark harness — one module per paper table/figure plus the roofline
-reader and kernel microbenches.  Prints ``name,us_per_call,derived`` CSV.
+"""Benchmark harness — one module per paper table/figure plus the kernel
+microbenches.  Prints ``name,us_per_call,derived`` CSV.
 
     PYTHONPATH=src python -m benchmarks.run            # bounded default set
     PYTHONPATH=src python -m benchmarks.run --full     # + 5000x5000 scale row
@@ -158,10 +158,8 @@ def _run_mode(args: argparse.Namespace) -> None:
         return
 
     from benchmarks import (
-        bench_autoshard_calibration,
         bench_fig11_quality,
         bench_kernels,
-        bench_roofline,
         bench_table6_mri,
         bench_table9_scale,
     )
@@ -171,8 +169,6 @@ def _run_mode(args: argparse.Namespace) -> None:
         ("fig11", lambda: bench_fig11_quality.run(full=args.full)),
         ("table9", lambda: bench_table9_scale.run(full=args.full)),
         ("kernels", lambda: bench_kernels.run()),
-        ("roofline", lambda: bench_roofline.run()),
-        ("autoshard_calibration", lambda: bench_autoshard_calibration.run()),
     ]
     print("name,us_per_call,derived")
     failures = 0

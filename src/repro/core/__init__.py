@@ -40,7 +40,6 @@ from repro.core.system_model import (
     synthetic_system,
     system_from_json,
     system_to_json,
-    tpu_fleet,
 )
 from repro.core.validate import verify_schedule
 from repro.core.workload_model import (
@@ -108,7 +107,6 @@ __all__ = [
     "system_from_json",
     "system_to_json",
     "testcase1_workloads",
-    "tpu_fleet",
     "verify_schedule",
     "workload_from_json",
     "workload_to_json",

@@ -297,11 +297,12 @@ def solve_milp(
             data.append(v)
     A = sp.csc_matrix((data, (ri, ci)), shape=(len(rows), nvar))
 
-    options: dict = {"disp": False}
+    # the gap is always passed: left out, HiGHS stops at its own default
+    # relative gap (1e-4) and reports a solution that far from the optimum
+    # as optimal
+    options: dict = {"disp": False, "mip_rel_gap": mip_rel_gap}
     if time_limit is not None:
         options["time_limit"] = time_limit
-    if mip_rel_gap:
-        options["mip_rel_gap"] = mip_rel_gap
 
     res = scipy_milp(
         c=c,

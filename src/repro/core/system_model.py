@@ -11,11 +11,6 @@ Implements the hierarchy  D (data center) ⊃ C (cluster) ⊃ N (node) with
   data-transfer rate ``P3``), Table III rows 12–14.
 
 JSON I/O follows the paper's Fig. 7 format (Snakemake-config compatible).
-
-The TPU-continuum builders at the bottom adapt the same algebra to a
-multi-pod TPU fleet: a pod is a cluster, a slice/chip-group is a node,
-``P2`` is bf16 FLOP/s, ``P3`` is ICI/DCN bandwidth.  This is the hardware
-adaptation described in DESIGN.md §2.
 """
 
 from __future__ import annotations
@@ -37,21 +32,14 @@ FEATURES = {
     "F6": "Storage SSD",
     "F7": "Network Omni-Path",
     "F8": "Network InfiniBand",
-    # TPU-continuum extensions (DESIGN.md §2). The paper's feature set is
-    # open-ended ("node-specific capabilities"); we register fabric/compute
-    # features for the TPU fleet under the same mechanism.
+    # TPU extensions. The paper's feature set is open-ended ("node-specific
+    # capabilities"); the ``tpu`` trace family (repro.service.traces) draws
+    # its workflows on F9 nodes.
     "F9": "TPU MXU (bf16 systolic)",
     "F10": "ICI intra-pod fabric",
     "F11": "DCN inter-pod fabric",
     "F12": "Host CPU (scheduler/solver node)",
 }
-
-# Hardware constants for the TPU v5e target (roofline §g).
-TPU_V5E_PEAK_FLOPS = 197e12  # bf16 FLOP/s per chip
-TPU_V5E_HBM_BW = 819e9  # bytes/s per chip
-TPU_V5E_ICI_BW = 50e9  # bytes/s per link (~4 links/chip on a 2D torus)
-TPU_V5E_HBM_BYTES = 16 * 1024**3  # 16 GiB HBM per chip
-DCN_BW = 25e9  # bytes/s per host pair across pods (conservative)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -293,63 +281,3 @@ def synthetic_system(
             )
         )
     return make_system(nodes)
-
-
-# -----------------------------------------------------------------------------
-# TPU continuum builders (hardware adaptation — DESIGN.md §2)
-# -----------------------------------------------------------------------------
-
-def tpu_slice_node(
-    name: str,
-    num_chips: int,
-    *,
-    fabric: str = "ici",
-) -> Node:
-    """Model a TPU slice as a paper-node.
-
-    R1 "cores"  -> chips; R2 "memory" -> aggregate HBM GiB;
-    P2          -> aggregate bf16 FLOP/s;
-    P3          -> bisection-ish fabric bandwidth in bytes/s.
-    """
-    bw = TPU_V5E_ICI_BW * max(1, num_chips // 2) if fabric == "ici" else DCN_BW
-    return Node(
-        name,
-        {
-            "cores": num_chips,
-            "memory": num_chips * TPU_V5E_HBM_BYTES / 1024**3,
-            "storage": 0.0,
-        },
-        frozenset({"F9", "F10" if fabric == "ici" else "F11"}),
-        {
-            "processing_speed": num_chips * TPU_V5E_PEAK_FLOPS,
-            "data_transfer_rate": bw,
-        },
-    )
-
-
-def tpu_fleet(
-    num_pods: int = 2,
-    chips_per_pod: int = 256,
-    slices_per_pod: int = 4,
-) -> System:
-    """A multi-pod TPU fleet as a paper ``System``.
-
-    Each pod contributes ``slices_per_pod`` schedulable slice-nodes joined by
-    ICI; cross-pod transfers ride DCN.  This is the system model the
-    continuum scheduler (``repro.core.continuum``) solves over.
-    """
-    nodes: list[Node] = []
-    pod_of: list[int] = []
-    for p in range(num_pods):
-        chips = chips_per_pod // slices_per_pod
-        for s in range(slices_per_pod):
-            nodes.append(tpu_slice_node(f"pod{p}/slice{s}", chips))
-            pod_of.append(p)
-    n = len(nodes)
-    dtr = np.full((n, n), DCN_BW, dtype=np.float64)
-    for i in range(n):
-        for j in range(n):
-            if pod_of[i] == pod_of[j]:
-                dtr[i, j] = TPU_V5E_ICI_BW * (chips_per_pod // slices_per_pod // 2)
-    np.fill_diagonal(dtr, np.inf)
-    return System(nodes=tuple(nodes), dtr=dtr)

@@ -1,8 +1,12 @@
-"""Pallas TPU kernels for the framework compute hot spots.
+"""The scheduler's device kernels: the population fitness evaluator.
 
-Layout per kernel: ``<name>.py`` holds the ``pl.pallas_call`` + BlockSpec
-implementation, ``ref.py`` the pure-jnp oracle, ``ops.py`` the jit dispatch
-wrapper (Pallas | jnp fallback).  See DESIGN.md section 6.
+* ``ref.py`` — the jnp evaluator (``population_makespan_ref``) the compiled
+  searches run;
+* ``select.py`` — the rank selects it places a task's cores with;
+* ``rowdma.py`` — the Pallas row-DMA write of each candidate's core-state
+  row (a TPU only; a scatter elsewhere);
+* ``makespan.py`` — the Pallas makespan kernel, taken only when asked for;
+* ``ops.py`` — the dispatch between the last and the jnp evaluator.
 """
 
 from repro.kernels import ops, ref

@@ -1,19 +1,18 @@
-"""Dispatch wrappers over the Pallas kernels and their jnp oracles.
+"""Dispatch of the population fitness evaluator.
 
-Selection policy:
+``population_makespan`` runs the jnp evaluator (``ref.py``) unless the
+Pallas makespan kernel (``makespan.py``) is asked for:
 
 * ``configure(use_pallas=...)`` or env ``REPRO_USE_PALLAS=1`` turns the
-  Pallas path on.  The platform alone decides how a kernel runs: natively on
-  a TPU, in the Pallas interpreter anywhere else
+  Pallas path on, and ``force=True`` (the ``pallas`` engine) takes it for
+  one call.  The platform alone decides how the kernel runs: natively on a
+  TPU, in the Pallas interpreter anywhere else
   (:func:`repro.kernels.makespan.interpret_mode`).
-* The default is the jnp oracle path — it is what the 512-device dry-run
-  lowers (Pallas does not lower to the XLA:CPU backend), and its FLOPs match
-  the kernel contract, so the roofline terms are representative.
-* ``population_makespan`` falls back to the oracle whenever the instance
-  exceeds the kernel's VMEM/SMEM envelope, or a join spreads over more
-  than one predecessor row.  The ``engine.traced.pallas`` /
-  ``engine.traced.ref`` counters count how often each path was *traced*:
-  under ``jit`` that is once per compiled program, not once per call.
+* The call falls back to the jnp evaluator whenever the instance exceeds
+  the kernel's VMEM/SMEM envelope, or a join spreads over more than one
+  predecessor row.  The ``engine.traced.pallas`` / ``engine.traced.ref``
+  counters count how often each path was *traced*: under ``jit`` that is
+  once per compiled program, not once per call.
 """
 
 from __future__ import annotations
@@ -26,9 +25,6 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.kernels import makespan, ref
-from repro.kernels.decode_attention import decode_attention_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
 @dataclasses.dataclass
@@ -118,117 +114,3 @@ def population_makespan(
         row_last=row_last,
     )
 
-
-def flash_attention(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    *,
-    causal: bool = True,
-    window: int | None = None,
-    softcap: float | None = None,
-    scale: float | None = None,
-    block_q: int = 128,
-    block_k: int = 128,
-    use_pallas: bool | None = None,
-) -> jax.Array:
-    use = _CONFIG.use_pallas if use_pallas is None else use_pallas
-    Sq, Skv = q.shape[2], k.shape[2]
-    if use and Sq % min(block_q, Sq) == 0 and Skv % min(block_k, Skv) == 0:
-        return flash_attention_pallas(
-            q,
-            k,
-            v,
-            causal=causal,
-            window=window,
-            softcap=softcap,
-            scale=scale,
-            block_q=block_q,
-            block_k=block_k,
-            interpret=makespan.interpret_mode(),
-        )
-    if Sq > 512 or Skv > 512:
-        # blockwise jnp path (flash-equivalent memory behaviour under XLA)
-        return _blockwise_attention_jnp(
-            q, k, v, causal=causal, window=window, softcap=softcap, scale=scale
-        )
-    return ref.flash_attention_ref(
-        q, k, v, causal=causal, window=window, softcap=softcap, scale=scale
-    )
-
-
-def _blockwise_attention_jnp(
-    q, k, v, *, causal, window, softcap, scale, block_q: int = 512
-):
-    """lax.map over query blocks against full K/V — bounds the live score
-    tensor to [block_q, Skv] so 32k prefill fits without a Pallas kernel.
-    Used by the dry-run lowering path."""
-    B, H, Sq, D = q.shape
-    if Sq % block_q != 0:
-        return ref.flash_attention_ref(
-            q, k, v, causal=causal, window=window, softcap=softcap, scale=scale
-        )
-    Skv = k.shape[2]
-    nq = Sq // block_q
-    qb = q.reshape(B, H, nq, block_q, D)
-
-    def one_block(args):
-        qi, qblk = args
-        offset = Skv - Sq + qi * block_q
-        return ref.flash_attention_block(
-            qblk, k, v, q_offset=offset, causal=causal, window=window,
-            softcap=softcap, scale=scale,
-        )
-
-    out = jax.lax.map(one_block, (jnp.arange(nq), jnp.moveaxis(qb, 2, 0)))
-    return jnp.moveaxis(out, 0, 2).reshape(B, H, Sq, D)
-
-
-def decode_attention(
-    q: jax.Array,
-    k_cache: jax.Array,
-    v_cache: jax.Array,
-    lengths: jax.Array,
-    *,
-    softcap: float | None = None,
-    scale: float | None = None,
-    block_k: int = 512,
-    use_pallas: bool | None = None,
-) -> jax.Array:
-    use = _CONFIG.use_pallas if use_pallas is None else use_pallas
-    S = k_cache.shape[2]
-    if use and S % min(block_k, S) == 0:
-        return decode_attention_pallas(
-            q,
-            k_cache,
-            v_cache,
-            lengths,
-            softcap=softcap,
-            scale=scale,
-            block_k=block_k,
-            interpret=makespan.interpret_mode(),
-        )
-    return ref.decode_attention_ref(
-        q, k_cache, v_cache, lengths, softcap=softcap, scale=scale
-    )
-
-
-def ssd_scan(
-    x: jax.Array,
-    dt: jax.Array,
-    A: jax.Array,
-    B_mat: jax.Array,
-    C_mat: jax.Array,
-    *,
-    chunk: int = 128,
-    use_pallas: bool | None = None,
-) -> tuple[jax.Array, jax.Array]:
-    use = _CONFIG.use_pallas if use_pallas is None else use_pallas
-    L = x.shape[1]
-    if use and L % min(chunk, L) == 0:
-        return ssd_scan_pallas(
-            x, dt, A, B_mat, C_mat, chunk=chunk, interpret=makespan.interpret_mode()
-        )
-    if L % min(chunk, L) == 0:
-        return ref.ssd_scan_chunked_ref(x, dt, A, B_mat, C_mat, chunk=min(chunk, L))
-    return ref.ssd_scan_ref(x, dt, A, B_mat, C_mat)

@@ -1,9 +1,9 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
+"""The population fitness evaluator in jnp.
 
-Each ``*_ref`` function defines the exact numerical contract its kernel must
-match (tests sweep shapes/dtypes and ``assert_allclose`` kernel vs. oracle).
-The oracles are also the production fallback path on backends without
-Pallas lowering (the CPU dry-run lowers these).
+``population_makespan_ref`` list-schedules every candidate of a population
+and returns its makespan and violations.  The compiled searches run it on
+every platform unless the Pallas kernel (``makespan.py``) is asked for, and
+it is the numerical contract that kernel must match.
 """
 
 from __future__ import annotations
@@ -20,10 +20,6 @@ _NEG = -1e30
 #: (never read)
 _PAD = 1e30
 
-
-# -----------------------------------------------------------------------------
-# population_makespan — the paper's metaheuristic fitness hot spot
-# -----------------------------------------------------------------------------
 
 def population_makespan_ref(
     assignments: jax.Array,  # [P, T] int32 (tasks topologically ordered)
@@ -162,231 +158,3 @@ def population_makespan_ref(
     if deadline is not None:
         violations = violations + jnp.sum(fin > deadline[:, None], axis=0).astype(jnp.float32)
     return makespan, violations
-
-
-# -----------------------------------------------------------------------------
-# flash attention (train / prefill)
-# -----------------------------------------------------------------------------
-
-def _attn_mask(sq: int, skv: int, *, causal: bool, window: int | None, q_offset: int = 0):
-    """[sq, skv] boolean mask. ``window`` = sliding-window size (SWA / gemma2
-    local layers): position q attends to kv in (q - window, q]."""
-    qi = jnp.arange(sq)[:, None] + q_offset
-    ki = jnp.arange(skv)[None, :]
-    mask = jnp.ones((sq, skv), dtype=bool)
-    if causal:
-        mask &= ki <= qi
-    if window is not None:
-        mask &= ki > qi - window
-    return mask
-
-
-def flash_attention_ref(
-    q: jax.Array,  # [B, H, Sq, D]
-    k: jax.Array,  # [B, Hkv, Skv, D]
-    v: jax.Array,  # [B, Hkv, Skv, D]
-    *,
-    causal: bool = True,
-    window: int | None = None,
-    softcap: float | None = None,
-    scale: float | None = None,
-) -> jax.Array:
-    """O(S²) reference attention with GQA, causal/window masking and logit
-    softcapping (gemma2). All accumulation in f32."""
-    B, H, Sq, D = q.shape
-    Hkv = k.shape[1]
-    group = H // Hkv
-    scale = D**-0.5 if scale is None else scale
-    qf = q.astype(jnp.float32) * scale
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    qg = qf.reshape(B, Hkv, group, Sq, D)
-    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, kf)
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-    mask = _attn_mask(Sq, k.shape[2], causal=causal, window=window, q_offset=k.shape[2] - Sq)
-    s = jnp.where(mask[None, None, None], s, _NEG)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqk,bhkd->bhgqd", p, vf)
-    return o.reshape(B, H, Sq, D).astype(q.dtype)
-
-
-def flash_attention_block(
-    q_block: jax.Array,  # [B, H, bq, D]
-    k: jax.Array,  # [B, Hkv, Skv, D]
-    v: jax.Array,  # [B, Hkv, Skv, D]
-    *,
-    q_offset,
-    causal: bool = True,
-    window: int | None = None,
-    softcap: float | None = None,
-    scale: float | None = None,
-) -> jax.Array:
-    """One query block against the full K/V at a (possibly traced) offset —
-    the building block of the blockwise-jnp attention used by the dry-run."""
-    B, H, bq, D = q_block.shape
-    Hkv, Skv = k.shape[1], k.shape[2]
-    group = H // Hkv
-    scale = D**-0.5 if scale is None else scale
-    # mixed-precision: f32 accumulation without materialized f32 K/V copies;
-    # scale folded post-einsum (exact, no operand rounding)
-    qg = q_block.reshape(B, Hkv, group, bq, D)
-    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k,
-                   preferred_element_type=jnp.float32) * scale
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-    rows = jnp.arange(bq)[:, None] + q_offset
-    cols = jnp.arange(Skv)[None, :]
-    mask = jnp.ones((bq, Skv), dtype=bool)
-    if causal:
-        mask &= cols <= rows
-    if window is not None:
-        mask &= cols > rows - window
-    s = jnp.where(mask[None, None, None], s, _NEG)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(v.dtype), v,
-                   preferred_element_type=jnp.float32)
-    return o.reshape(B, H, bq, D).astype(q_block.dtype)
-
-
-# -----------------------------------------------------------------------------
-# decode attention (single-token query vs. KV cache)
-# -----------------------------------------------------------------------------
-
-def decode_attention_ref(
-    q: jax.Array,  # [B, H, D]
-    k_cache: jax.Array,  # [B, Hkv, S, D]
-    v_cache: jax.Array,  # [B, Hkv, S, D]
-    lengths: jax.Array,  # [B] int32 — valid cache entries per sequence
-    *,
-    softcap: float | None = None,
-    scale: float | None = None,
-) -> jax.Array:
-    B, H, D = q.shape
-    Hkv, S = k_cache.shape[1], k_cache.shape[2]
-    group = H // Hkv
-    scale = D**-0.5 if scale is None else scale
-    # mixed-precision einsums: f32 accumulation WITHOUT materializing f32
-    # copies of the cache (§Perf: the upcast cost 2.5× the decode memory
-    # term; the Pallas kernel accumulates in registers — this matches it).
-    # Scale folded post-einsum (exact, no operand rounding).
-    qg = q.reshape(B, Hkv, group, D)
-    s = jnp.einsum("bhgd,bhkd->bhgk", qg, k_cache,
-                   preferred_element_type=jnp.float32) * scale
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-    valid = jnp.arange(S)[None, None, None, :] < lengths[:, None, None, None]
-    s = jnp.where(valid, s, _NEG)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgk,bhkd->bhgd", p.astype(v_cache.dtype), v_cache,
-                   preferred_element_type=jnp.float32)
-    return o.reshape(B, H, D).astype(q.dtype)
-
-
-# -----------------------------------------------------------------------------
-# Mamba2 SSD scan
-# -----------------------------------------------------------------------------
-
-def ssd_scan_ref(
-    x: jax.Array,  # [B, L, H, P]
-    dt: jax.Array,  # [B, L, H]  (already softplus'd, > 0)
-    A: jax.Array,  # [H]        (negative)
-    B_mat: jax.Array,  # [B, L, G, N]
-    C_mat: jax.Array,  # [B, L, G, N]
-    *,
-    init_state: jax.Array | None = None,  # [B, H, P, N]
-) -> tuple[jax.Array, jax.Array]:
-    """Sequential (exact) SSD recurrence:
-
-        S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_tᵀ ;   y_t = S_t C_tᵀ
-
-    Returns (y [B,L,H,P], final_state [B,H,P,N]).  Heads are grouped over
-    B/C (``G`` groups, ``H % G == 0``).  f32 state."""
-    Bsz, L, H, P = x.shape
-    G, N = B_mat.shape[2], B_mat.shape[3]
-    rep = H // G
-    Bh = jnp.repeat(B_mat, rep, axis=2)  # [B, L, H, N]
-    Ch = jnp.repeat(C_mat, rep, axis=2)
-    if init_state is None:
-        init_state = jnp.zeros((Bsz, H, P, N), jnp.float32)
-
-    xf = x.astype(jnp.float32)
-    dtf = dt.astype(jnp.float32)
-    Af = A.astype(jnp.float32)
-
-    def step(state, inp):
-        xt, dtt, Bt, Ct = inp  # [B,H,P], [B,H], [B,H,N], [B,H,N]
-        dA = jnp.exp(dtt * Af[None, :])  # [B,H]
-        state = state * dA[..., None, None] + (dtt[..., None, None] * xt[..., None] * Bt[..., None, :])
-        y = jnp.einsum("bhpn,bhn->bhp", state, Ct)
-        return state, y
-
-    inputs = (
-        jnp.moveaxis(xf, 1, 0),
-        jnp.moveaxis(dtf, 1, 0),
-        jnp.moveaxis(Bh.astype(jnp.float32), 1, 0),
-        jnp.moveaxis(Ch.astype(jnp.float32), 1, 0),
-    )
-    final, ys = jax.lax.scan(step, init_state, inputs)
-    y = jnp.moveaxis(ys, 0, 1).astype(x.dtype)
-    return y, final
-
-
-def ssd_scan_chunked_ref(
-    x: jax.Array,
-    dt: jax.Array,
-    A: jax.Array,
-    B_mat: jax.Array,
-    C_mat: jax.Array,
-    *,
-    chunk: int = 64,
-    init_state: jax.Array | None = None,
-) -> tuple[jax.Array, jax.Array]:
-    """Chunked SSD (state-space *duality* form, arXiv:2405.21060): intra-chunk
-    attention-like matmuls + inter-chunk state recurrence.  Mathematically
-    identical to :func:`ssd_scan_ref`; this is the matmul-dominant layout the
-    Pallas kernel implements (MXU-friendly)."""
-    Bsz, L, H, P = x.shape
-    G, N = B_mat.shape[2], B_mat.shape[3]
-    rep = H // G
-    assert L % chunk == 0, (L, chunk)
-    nC = L // chunk
-    Bh = jnp.repeat(B_mat, rep, axis=2).astype(jnp.float32)
-    Ch = jnp.repeat(C_mat, rep, axis=2).astype(jnp.float32)
-    xf = x.astype(jnp.float32)
-    dtf = dt.astype(jnp.float32)
-    Af = A.astype(jnp.float32)
-    if init_state is None:
-        init_state = jnp.zeros((Bsz, H, P, N), jnp.float32)
-
-    # reshape to chunks: [B, nC, Q, H, ...]
-    xq = xf.reshape(Bsz, nC, chunk, H, P)
-    dq = dtf.reshape(Bsz, nC, chunk, H)
-    Bq = Bh.reshape(Bsz, nC, chunk, H, N)
-    Cq = Ch.reshape(Bsz, nC, chunk, H, N)
-
-    a = dq * Af[None, None, None, :]  # log decay per step  [B,nC,Q,H]
-    a_cs = jnp.cumsum(a, axis=2)  # inclusive cumsum within chunk
-
-    def chunk_step(state, inp):
-        xq_c, dq_c, Bq_c, Cq_c, a_c, acs_c = inp  # [B, Q, H, ...]
-        # intra-chunk: y[i] += sum_{j<=i} C_i·B_j exp(acs_i - acs_j) dt_j x_j
-        seg = acs_c[:, :, None, :] - acs_c[:, None, :, :]  # [B, Qi, Qj, H]
-        mask = jnp.tril(jnp.ones((chunk, chunk), bool))
-        decay = jnp.where(mask[None, :, :, None], jnp.exp(seg), 0.0)
-        cb = jnp.einsum("bihn,bjhn->bijh", Cq_c, Bq_c)
-        m = cb * decay
-        y_intra = jnp.einsum("bijh,bjh,bjhp->bihp", m, dq_c, xq_c)
-        # inter-chunk: contribution of incoming state
-        y_inter = jnp.einsum("bihn,bhpn,bih->bihp", Cq_c, state, jnp.exp(acs_c))
-        # state update
-        a_tot = acs_c[:, -1, :]  # [B, H]
-        w = jnp.exp(a_tot[:, None, :] - acs_c) * dq_c  # [B, Q, H]
-        ds = jnp.einsum("bjh,bjhp,bjhn->bhpn", w, xq_c, Bq_c)
-        state = state * jnp.exp(a_tot)[..., None, None] + ds
-        return state, y_intra + y_inter
-
-    inputs = tuple(jnp.moveaxis(t, 1, 0) for t in (xq, dq, Bq, Cq, a, a_cs))
-    final, ys = jax.lax.scan(chunk_step, init_state, inputs)
-    y = jnp.moveaxis(ys, 0, 1).reshape(Bsz, L, H, P).astype(x.dtype)
-    return y, final

@@ -1,8 +1,7 @@
 """Seeded continuum topology generator (ROADMAP item 4).
 
 Every system scheduled so far is a small hand-built node set
-(:func:`repro.core.system_model.mri_system`, ``synthetic_system``,
-``tpu_fleet``).  This module generates IoT/edge/cloud/HPC continua at
+(:func:`repro.core.system_model.mri_system`, ``synthetic_system``).  This module generates IoT/edge/cloud/HPC continua at
 realistic scale following the tiered resource taxonomy of the SPEC-RG
 reference architecture (arxiv 2207.04159): a declarative, JSON-round-
 trippable :class:`TopologySpec` expands deterministically into a paper

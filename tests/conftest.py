@@ -1,6 +1,6 @@
 import os
 
-# Tests run on the REAL device topology (1 CPU device). Only the dry-run
-# launcher forces 512 fake devices — never set that here (spec requirement).
+# Tests run on the REAL device topology (1 CPU device). Tests that need a
+# mesh start a subprocess with more virtual devices.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=1")

@@ -14,7 +14,6 @@ from repro.core import (
     synthetic_system,
     system_from_json,
     system_to_json,
-    tpu_fleet,
     verify_schedule,
 )
 
@@ -59,13 +58,6 @@ def test_fig7_example_parses():
     assert s.nodes[0].cores == 4
     assert s.nodes[1].cores == 12
     assert s.nodes[0].provides({"F1"})
-
-
-def test_tpu_fleet_structure():
-    fleet = tpu_fleet(num_pods=2, chips_per_pod=256, slices_per_pod=4)
-    assert fleet.num_nodes == 8
-    assert fleet.dtr[0, 1] > fleet.dtr[0, 4]  # ICI > DCN
-    assert all(n.provides({"F9"}) for n in fleet.nodes)
 
 
 def test_solve_auto_on_mri_is_optimal():
