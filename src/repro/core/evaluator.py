@@ -22,7 +22,7 @@ Execution itself lives one layer down, in :mod:`repro.engine`:
 * :func:`evaluate_assignment` (here) wraps the ``oracle`` backend — the one
   incremental simulator in :mod:`repro.engine.sim` (ground truth for tests),
 * :func:`make_fitness_fn` routes through the engine registry
-  (:mod:`repro.engine.backends`): ``jax`` (shared jitted rank-select
+  (:mod:`repro.engine.backends`): ``jax`` (shared jitted jnp
   evaluator) or ``pallas`` (the TPU kernel), both bit-for-bit equal to the
   f32 oracle,
 * the batched multi-instance API (:func:`make_batched_fitness_fn`,

@@ -6,7 +6,7 @@ is a registered :class:`ScheduleEngine` carrying capability metadata —
 
 * ``oracle`` — the numpy incremental simulator (:mod:`repro.engine.sim`);
   ground truth, per-task start/finish times, any dtype;
-* ``jax`` — the jitted rank-select population evaluator (XLA caches by
+* ``jax`` — the jitted jnp population evaluator (XLA caches by
   shape, so every technique / sweep point in the same bucket shares one
   compiled program); also the vmapped multi-instance batch path;
 * ``pallas`` — the TPU Pallas kernel (Mosaic on a TPU, the Pallas
@@ -398,7 +398,7 @@ class OracleEngine(ScheduleEngine):
 
 @register_engine("jax")
 class JaxEngine(ScheduleEngine):
-    """The jitted rank-select population evaluator over packed arrays —
+    """The jitted jnp population evaluator over packed arrays —
     one compiled program per (shape bucket, usage mode), shared by every
     technique and sweep point."""
 

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.kernels import rowdma
-from repro.kernels.select import kth_from_ranks, stable_ranks, update_from_ranks
+from repro.kernels.select import insert_sorted, kth_sorted
 
 _NEG = -1e30
 #: what a stored core-state row holds beyond its node's ``CMAX`` cores
@@ -65,8 +65,11 @@ def population_makespan_ref(
     that value, so every number is the one an indexed read gives.
 
     The core state ``[P, N, W]`` stores each row ``W`` lanes wide
-    (:func:`repro.kernels.rowdma.row_width`); a step reads the first
-    ``CMAX`` and writes every candidate's new row back through
+    (:func:`repro.kernels.rowdma.row_width`), its first ``CMAX`` lanes in
+    ascending order (``init_free`` is sorted on entry), so a step reads its
+    ``c``-th free core with one masked sum and shifts the new row into place
+    with static lane shifts (:mod:`repro.kernels.select`).  A step reads
+    the first ``CMAX`` and writes every candidate's new row back through
     :func:`repro.kernels.rowdma.write_rows`: async row copies on a TPU,
     which skip a row whose ``row_last`` is false, XLA's scatter elsewhere.
     The trace-time counter ``engine.traced.row_dma`` counts evaluators
@@ -98,14 +101,10 @@ def population_makespan_ref(
 
     def place(core_free, i, ready, c, d):  # one candidate's node
         stored = core_free[i]
-        row = stored[:cmax]
-        # O(CMAX²) comparison-rank select — no sort, no gather/scatter;
-        # shares the primitive (and thus bit-exact values) with the Pallas
-        # kernel.
-        ranks = stable_ranks(row)
-        kth = kth_from_ranks(row, ranks, c)
+        row = stored[:cmax]  # ascending
+        kth = kth_sorted(row, c)
         f = jnp.maximum(ready, kth) + d
-        new = update_from_ranks(row, ranks, c, f)
+        new = insert_sorted(row, c, f)
         return jnp.pad(new, (0, width - cmax), constant_values=_PAD), stored, f
 
     def write(core_free, i, ready, c, d, last=None):
@@ -142,7 +141,10 @@ def population_makespan_ref(
     # state shaped from the inputs, so under shard_map the carry varies over
     # the same mesh axes as what the scan writes into it
     fin0 = jnp.zeros_like(dur, dtype=jnp.float32)
-    stored0 = jnp.pad(init_free, ((0, 0), (0, width - cmax)), constant_values=_PAD)
+    # each row ascending, as ``place`` keeps it (equal times are alike, so
+    # the sort need not be stable); the padding stays last
+    stored0 = jnp.pad(jnp.sort(init_free, axis=-1, stable=False),
+                      ((0, 0), (0, width - cmax)), constant_values=_PAD)
     core_free0 = jnp.broadcast_to(stored0, (P,) + stored0.shape)
     obs.METRICS.counter("engine.traced.row_dma").inc()  # trace-time count
     if pred_rows.shape[0] == T:

@@ -1,28 +1,29 @@
-"""Stable k-th-smallest selection via comparison ranks — *the* core-selection
-primitive of the schedule evaluators.
+"""Core selection on sorted core-free rows — *the* core-selection primitive
+of the jnp schedule evaluator.
 
 List scheduling needs, per task step, the time at which ``c`` cores of the
 assigned node are simultaneously free: the ``c``-th smallest entry of that
-node's core-free row, followed by replacing the ``c`` stably-smallest entries
-with the task's finish time.  A sort gives both, but the TPU VPU has no sort
-primitive and XLA's ``argsort`` inside the innermost T-step scan costs
-O(CMAX log CMAX) *plus* a gather/scatter pair.  The comparison-rank trick
-used here is branch-free, gather-free, and purely elementwise:
+node's core-free row, followed by replacing the ``c`` smallest entries with
+the task's finish time ``f``.  The evaluator only ever reads a row through
+that ``c``-th smallest value and only ever writes it by that replacement, so
+it depends on the *multiset* of a row's entries, not their order.  Keeping
+each row in ascending order takes both operations from O(CMAX²) compares to
+O(CMAX log CMAX) lane operations, with no sort, no gather and no scatter
+inside the scan:
 
-    rank[m] = #{m' : row[m'] < row[m]  or  (row[m'] == row[m] and m' < m)}
+* the ``c``-th smallest is ``row[c-1]``, read by a masked sum over the lanes;
+* with ``s[j] = row[j+c]`` (``+inf`` past the end) and ``f >= row[c-1]``,
+  the new ascending row is ``max(min(s, f), row)``: ``s[j]`` while it is
+  below ``f``, then ``c`` copies of ``f``, then ``row[j]`` once that is at
+  least ``f``.  The shift by ``c`` goes one digit of ``c``
+  (:data:`DIGIT_BITS` bits) a stage, each stage a select among static lane
+  shifts.
 
-``rank`` is a permutation of ``0..C-1`` (ties broken by index — the same
-stable order ``np.argsort(kind="stable")`` produces), so the value with
-``rank == c-1`` is the stable c-th smallest, and ``rank < c`` masks the
-stably-smallest ``c`` entries for the update.  O(CMAX²) compares, but they
-vectorize perfectly on the VPU / in XLA — and since the *values* written are
-identical to the sort-based formulation, results match the numpy oracle
-bit-for-bit.
-
-Shared by ``repro.kernels.makespan`` (inside the Pallas kernel),
-``repro.kernels.ref`` (the jnp oracle), and through it every metaheuristic
-fitness function.  Ranks are returned as f32 (exact for C < 2²⁴) so the
-kernel can keep its core counts in vector registers as f32.
+The values written are the ones a stable sort gives, so results match the
+numpy oracle (and the Pallas kernel, which keeps the comparison-rank rule
+``rank[m] = #{m' : row[m'] < row[m] or (row[m'] == row[m] and m' < m)}``
+inline) bit for bit.  Both functions broadcast over leading dims: ``c`` and
+``f`` carry the row's leading shape.
 """
 
 from __future__ import annotations
@@ -30,41 +31,43 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+#: bits of ``c`` the shifter takes per stage; XLA:TPU fuses a stage's selects
+DIGIT_BITS = 3
 
-def stable_ranks(row: jax.Array) -> jax.Array:
-    """Comparison rank of every entry along the last axis.
 
-    ``row [..., C]`` → ``ranks [..., C]`` (f32), a permutation of ``0..C-1``
-    matching stable ascending sort order.
+def kth_sorted(row: jax.Array, c) -> jax.Array:
+    """``c``-th smallest (1-indexed) of the ascending ``row [..., C]``.
+
+    ``1 <= c <= C``; a masked sum of one value and zeros is that value.
     """
-    c = row.shape[-1]
-    # 2D iotas (TPU requires ≥2D); axis 0 indexes m, axis 1 indexes m'.
-    im = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    imp = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    at_m = row[..., :, None]
-    at_mp = row[..., None, :]
-    before = (at_mp < at_m) | ((at_mp == at_m) & (imp < im))
-    return jnp.sum(before.astype(jnp.float32), axis=-1)
+    lanes = jnp.arange(row.shape[-1], dtype=jnp.int32)
+    c = jnp.asarray(c, jnp.int32)[..., None]
+    return jnp.sum(jnp.where(lanes == c - 1, row, 0.0), axis=-1)
 
 
-def kth_from_ranks(row: jax.Array, ranks: jax.Array, c) -> jax.Array:
-    """Stable ``c``-th smallest (1-indexed) along the last axis.
+def _shift_left(x: jax.Array, s: int) -> jax.Array:
+    """``x[..., j + s]`` at lane ``j``, ``+inf`` past the end.
 
-    ``c`` must broadcast against ``row``'s leading dims and satisfy
-    ``1 <= c <= C`` (exactly one entry has ``rank == c-1``).
-    """
-    cf = jnp.asarray(c, jnp.float32)
-    hit = ranks == (cf[..., None] - 1.0)
-    return jnp.sum(jnp.where(hit, row, 0.0), axis=-1)
+    A pad with a negative low edge: XLA:TPU fuses it into its consumer,
+    where a slice at a lane offset is a copy of its own."""
+    fill = jnp.array(jnp.inf, x.dtype)
+    return jax.lax.pad(x, fill, [(0, 0, 0)] * (x.ndim - 1) + [(-s, s, 0)])
 
 
-def update_from_ranks(row: jax.Array, ranks: jax.Array, c, fill) -> jax.Array:
-    """Replace the ``c`` stably-smallest entries of ``row`` with ``fill``."""
-    cf = jnp.asarray(c, jnp.float32)
-    fillf = jnp.asarray(fill, row.dtype)
-    return jnp.where(ranks < cf[..., None], fillf[..., None], row)
-
-
-def kth_smallest(row: jax.Array, c) -> jax.Array:
-    """Convenience: stable c-th smallest without reusing the ranks."""
-    return kth_from_ranks(row, stable_ranks(row), c)
+def insert_sorted(row: jax.Array, c, f) -> jax.Array:
+    """The ascending ``row [..., C]`` with its ``c`` smallest entries replaced
+    by ``f >= row[c-1]``, still ascending."""
+    width = row.shape[-1]
+    c = jnp.asarray(c, jnp.int32)[..., None]
+    f = jnp.asarray(f, row.dtype)[..., None]
+    # row[j + c] at lane j (+inf past the end), one digit of c a stage
+    shifted = row
+    for low in range(0, width.bit_length(), DIGIT_BITS):
+        digit = (c >> low) & ((1 << DIGIT_BITS) - 1)
+        out = shifted
+        for d in range(1, 1 << DIGIT_BITS):
+            if d << low > width:
+                break
+            out = jnp.where(digit == d, _shift_left(shifted, d << low), out)
+        shifted = out
+    return jnp.maximum(jnp.minimum(shifted, f), row)

@@ -1,6 +1,6 @@
 """Fast-path equivalence & batching tests (the PR-1 acceptance sweep):
 
-* the shared rank-select primitive == numpy stable sort semantics,
+* the sorted-row core select and insert == numpy stable sort semantics,
 * jnp fitness == Pallas kernel (interpret, resident AND streamed modes)
   == numpy f32 oracle, **bit-for-bit**, over randomized problem shapes
   (wide/narrow core windows, multi-core tasks, cross-node transfers),
@@ -32,7 +32,7 @@ from repro.engine import bucket_of, fitness_cache_sizes, pack
 from repro.core.system_model import make_system
 from repro.core.workload_model import random_layered_workflow, synthetic_workload
 from repro.kernels.makespan import population_makespan_pallas
-from repro.kernels.select import kth_from_ranks, stable_ranks, update_from_ranks
+from repro.kernels.select import insert_sorted, kth_sorted
 
 
 def _narrow_system(num_nodes: int, cores: int = 2):
@@ -63,25 +63,37 @@ def _problems():
 
 
 # -----------------------------------------------------------------------------
-# rank-select primitive
+# core selection on sorted rows
 # -----------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("seed,width", [(0, 5), (1, 16), (2, 64), (3, 7)])
+@pytest.mark.parametrize("seed,width", [(0, 5), (1, 16), (2, 64), (3, 7), (4, 1), (5, 512)])
 def test_rank_select_matches_stable_sort(seed, width):
+    """The c-th smallest of an ascending row and the row with its c smallest
+    entries replaced by ``f`` are numpy's stable sort's, element for element
+    (so the new row is ascending too), over heavy ties and 1e30 padding, for
+    ``f`` equal to the c-th smallest and above it.  512 is the packer's core
+    cap."""
+    import jax
+
     rng = np.random.default_rng(seed)
-    # heavy ties stress the stable tie-break
-    row = rng.choice([0.0, 1.5, 2.0, 7.25, 1e30], size=width).astype(np.float32)
-    ranks = np.asarray(stable_ranks(jnp.asarray(row)))
-    assert sorted(ranks.tolist()) == list(range(width))  # a permutation
-    srow = np.sort(row, kind="stable")
-    for c in (1, 2, width // 2 + 1, width):
-        kth = np.asarray(kth_from_ranks(jnp.asarray(row), jnp.asarray(ranks), c))
-        assert kth == srow[c - 1]
-        upd = np.asarray(update_from_ranks(jnp.asarray(row), jnp.asarray(ranks), c, 99.0))
-        # multiset semantics: c smallest replaced with the fill value
-        expect = np.sort(np.concatenate([srow[c:], np.full(c, 99.0, np.float32)]))
-        np.testing.assert_array_equal(np.sort(upd), expect)
+    cases = []
+    for _ in range(8):
+        # heavy ties stress the tie handling; padding sorts last
+        srow = np.sort(rng.choice([0.0, 1.5, 2.0, 7.25, 1e30], size=width).astype(np.float32))
+        for c in sorted({c for c in (1, 2, width // 2 + 1, width) if c <= width}):
+            kth = srow[c - 1]
+            for f in {float(kth)} | {v for v in (2.0, 7.25, 99.0, 3e30) if v > kth}:
+                cases.append((srow, c, np.float32(f)))
+    rows = jnp.asarray(np.stack([r for r, _, _ in cases]))
+    cs = jnp.asarray([c for _, c, _ in cases], jnp.int32)
+    fs = jnp.asarray([f for _, _, f in cases], jnp.float32)
+    kth = np.asarray(jax.jit(kth_sorted)(rows, cs))
+    new = np.asarray(jax.jit(insert_sorted)(rows, cs, fs))
+    for k, (srow, c, f) in enumerate(cases):
+        assert kth[k] == srow[c - 1]
+        expect = np.sort(np.concatenate([srow[c:], np.full(c, f, np.float32)]))
+        np.testing.assert_array_equal(new[k], expect)
 
 
 # -----------------------------------------------------------------------------

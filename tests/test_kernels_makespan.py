@@ -250,6 +250,32 @@ def test_task_step_reads_whole_rows_at_table9_bucket(batch):
         assert sizes[-1] == operand[-1] > 1, (operand, sizes)
 
 
+@pytest.mark.parametrize("rows", [24, 40], ids=["one_row_per_task", "split_rows"])
+def test_core_selection_has_no_cmax_squared_operand(rows):
+    """The step selects a node's c-th free core on its sorted row: no
+    equation of the scan body has an operand or result whose last two dims
+    are ``(CMAX, CMAX)``, the comparison tensor of a rank select.  ``N``,
+    ``T`` and ``P`` differ from ``CMAX``, so nothing else can match."""
+    import jax
+
+    T, N, C, K, P = 24, 12, 16, 4, 8
+    shapes = {"durations": ((T, N), jnp.float32), "cores": ((T,), jnp.int32),
+              "data": ((T,), jnp.float32), "feasible": ((T, N), jnp.bool_),
+              "release": ((T,), jnp.float32), "pred_rows": ((rows, K), jnp.int32),
+              "row_task": ((rows,), jnp.int32), "row_last": ((rows,), jnp.bool_),
+              "dtr": ((N, N), jnp.float32), "init_free": ((N, C), jnp.float32),
+              "node_cores": ((N,), jnp.int32), "deadline": ((T,), jnp.float32)}
+    arrays = {k: jax.ShapeDtypeStruct(s, d) for k, (s, d) in shapes.items()}
+    pop = jax.ShapeDtypeStruct((P, T), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda a, arr: population_makespan_ref(a, **arr))(pop, arrays).jaxpr
+    scans = [e for e in _eqns(jaxpr) if e.primitive.name == "scan"]
+    assert [e.params["length"] for e in scans] == [rows]
+    body = scans[0].params["jaxpr"].jaxpr
+    shapes = [v.aval.shape for e in _eqns(body) for v in (*e.invars, *e.outvars)]
+    assert any(s[-1:] == (C,) for s in shapes)  # the step does work on rows
+    assert not [s for s in shapes if s[-2:] == (C, C)]
+
+
 # -----------------------------------------------------------------------------
 # predecessor rows: a join spread over several rows scores bit for bit like
 # one dense row of all its predecessors
@@ -364,6 +390,71 @@ def test_one_row_per_task_packs_the_dense_matrix():
         return [str(e) for e in _eqns(jaxpr) if e.primitive.name == "scan"]
 
     assert scans() == scans(row_task=arr["row_task"], row_last=arr["row_last"])
+
+
+def _list_schedule_f32(A, arr):
+    """Each candidate list-scheduled in f32 numpy: a task is ready when its
+    release and its predecessors' data are, starts at the stable ``c``-th
+    smallest free time of its node's core row (``np.sort(kind="stable")``
+    order, rows in any order) and takes those ``c`` cores."""
+    d = {k: np.asarray(v) for k, v in arr.items()}
+    T = d["durations"].shape[0]
+    preds = [[] for _ in range(T)]
+    for s, j in enumerate(d["row_task"]):
+        preds[j] += [int(p) for p in d["pred_rows"][s] if p >= 0]
+    mk, viol = [], []
+    for a in np.asarray(A):
+        free = d["init_free"].copy()
+        fin = np.zeros(T, np.float32)
+        for j in range(T):
+            i = a[j]
+            ready = d["release"][j]
+            for p in preds[j]:
+                transfer = np.float32(0) if a[p] == i else d["data"][p] / d["dtr"][a[p], i]
+                ready = max(ready, fin[p] + transfer)
+            c = max(min(int(d["cores"][j]), int(d["node_cores"][i])), 1)
+            order = np.argsort(free[i], kind="stable")
+            fin[j] = max(ready, free[i, order[c - 1]]) + d["durations"][j, i]
+            free[i, order[:c]] = fin[j]
+        mk.append(fin.max(initial=0.0))
+        viol.append((~d["feasible"][np.arange(T), a]).sum() + (fin > d["deadline"]).sum())
+    return np.array(mk, np.float32), np.array(viol, np.float32)
+
+
+@pytest.mark.parametrize("name", ["table9_like", "split_rows"])
+def test_unsorted_busy_cores_score_as_a_stable_sort_does(name):
+    """An ``init_free`` whose real cores hold busy times out of order scores
+    as the list scheduler that takes the stable c-th smallest of the
+    unsorted row: the evaluator sorts the rows on entry, so its sorted-row
+    select sees the same multiset."""
+    import jax
+
+    if name == "table9_like":
+        prob = _layered(40, 8, 41)
+        pk = pack(prob, use_cache=False)
+    else:  # a Montage join over several rows (S > T)
+        probs, _, bucket = _row_case("3x3_k2")
+        prob = probs[0]
+        pk = pack(prob, bucket, use_cache=False)
+        assert pk.bucket[4] > pk.bucket[0]
+    arr = dict(pk.device_arrays())
+    rng = np.random.default_rng(sum(map(ord, name)))
+    free = np.array(arr["init_free"])
+    for i, k in enumerate(np.asarray(arr["node_cores"])):
+        free[i, :k] = rng.choice([0.0, 2.5, 0.75, 6.0, 0.75, 11.0], size=k)
+    assert (np.diff(free, axis=1) < 0).any()  # rows out of order
+    arr["init_free"] = jnp.asarray(free)
+    pop = rng.integers(0, prob.num_nodes, (8, prob.num_tasks))
+    A = jnp.asarray(_padded(pop, pk.bucket[0]))
+    kw = {k: arr[k] for k in _EVAL_KEYS + ("deadline", "row_task", "row_last")}
+    mk, viol = jax.jit(population_makespan_ref)(A, **kw)
+    want_mk, want_viol = _list_schedule_f32(A, kw)
+    np.testing.assert_array_equal(np.asarray(mk), want_mk)
+    np.testing.assert_array_equal(np.asarray(viol), want_viol)
+    # the busy cores matter: the scores differ from an idle start
+    idle = kw | {"init_free": pk.device_arrays()["init_free"]}
+    idle_mk, _ = jax.jit(population_makespan_ref)(A, **idle)
+    assert (np.asarray(idle_mk) != want_mk).any()
 
 
 # -----------------------------------------------------------------------------
